@@ -37,9 +37,10 @@ the run: device throughput >= host, and bitwise-equal outputs.
 (``ShardedGritIndex`` slab-routed predict/insert vs a distributed refit
 per query batch, on a mesh over every visible device) and writes
 ``BENCH_4.json``; the >= 10x sharded-predict-vs-distributed-refit
-check gates the run.  On single-device hosts it forces a 4-way host
-mesh via XLA_FLAGS (set before jax is first imported, which is why the
-flag must be handled before any benchmark module loads).  The same
+check gates the run.  Under ``JAX_PLATFORMS=cpu`` it forces a 4-way
+host mesh via XLA_FLAGS (set before jax is first imported, which is why
+the flag must be handled before any benchmark module loads); on an
+accelerator the mesh is the visible chips.  The same
 invocation then writes ``BENCH_7.json`` (traced-fit stage attribution,
 coverage >= 90%) and ``BENCH_8.json`` (warm distributed fit <= host
 grit fit at equal total n, with the halo padding-waste <= 25% and
@@ -389,8 +390,8 @@ def main() -> int:
     ap.add_argument("--dist-n", type=int, default=50_000,
                     help="fit-set size for --distributed")
     ap.add_argument("--dist-shards", type=int, default=4,
-                    help="host devices to force for --distributed when "
-                         "the platform has only one")
+                    help="host devices to force for --distributed "
+                         "under JAX_PLATFORMS=cpu")
     ap.add_argument("--trace-n", type=int, default=None,
                     help="fit-set size for the traced-fit attribution "
                          "half of --distributed (default: --dist-n)")
@@ -419,9 +420,11 @@ def main() -> int:
                          else "BENCH_3.json" if args.serve
                          else "BENCH_2.json")
 
-    if args.distributed:
+    if args.distributed and \
+            os.environ.get("JAX_PLATFORMS", "").strip().lower() == "cpu":
         # must run before anything imports jax: device-count flags are
-        # read at first import
+        # read at first import.  Only on a CPU-only run: on an
+        # accelerator the mesh is the chips, never forced host devices
         if "xla_force_host_platform_device_count" not in \
                 os.environ.get("XLA_FLAGS", ""):
             os.environ["XLA_FLAGS"] = (
@@ -430,6 +433,10 @@ def main() -> int:
                 f"{args.dist_shards}").strip()
         assert "jax" not in sys.modules, \
             "--distributed must configure XLA before jax is imported"
+    from repro import compile_cache
+    compile_cache.enable()
+
+    if args.distributed:
         from benchmarks import dist_bench as DS
         rows = DS.bench_dist_serve(n=args.dist_n)
         csv_text = _print_csv(rows)

@@ -3,10 +3,10 @@
 Every distinct input shape (and every distinct static value) is a new
 XLA compile.  The serving stack keeps compile counts bounded by padding
 data-dependent sizes through the pow2/bucketing helpers
-(``_pow2_at_least`` / ``_pad_pow2`` / ``_pad_rows`` / ``_pad_feat``)
-and the persisted ``*_cap`` attributes before anything reaches a jitted
-callable.  This rule flags two ways a change can silently reintroduce
-per-request compiles:
+(``_pow2_at_least`` / ``_pad_pow2`` / ``_pad_rows`` / ``_pad_feat`` /
+``_flat_bucket``) and the persisted ``*_cap`` attributes before
+anything reaches a jitted callable.  This rule flags two ways a change
+can silently reintroduce per-request compiles:
 
 * a jitted callee fed ``jnp.asarray(x)`` / ``jnp.array(x)`` where ``x``
   involves a locally-assigned array that never went through a bucketing
@@ -29,6 +29,7 @@ from ..report import Violation
 #: helpers whose output is shape-bucketed by construction
 BUCKETING_HELPERS = frozenset({
     "_pow2_at_least", "_pad_pow2", "_pad_rows", "_pad_feat",
+    "_flat_bucket",
 })
 
 _CONVERTERS = frozenset({

@@ -162,10 +162,14 @@ def _masked_prune_jnp(A, va, B, vb, p, q, eps):
     the merge hot loop (they dominated its wall on CPU)."""
     dpq = jnp.linalg.norm(p - q)
     sigma = dpq - eps
+    # full-f32 dots: a TPU computes a default-precision f32 dot in bf16
+    # passes, which would perturb the cosines past the prune's margins
+    hi = jax.lax.Precision.HIGHEST
     py = B - p[None, :]
     dpy = jnp.linalg.norm(py, axis=1)
     safe_dpy = jnp.maximum(dpy, 1e-30)
-    cos_b = jnp.clip((py @ (q - p)) / (safe_dpy * jnp.maximum(dpq, 1e-30)), -1., 1.)
+    cos_b = jnp.clip(jnp.dot(py, q - p, precision=hi)
+                     / (safe_dpy * jnp.maximum(dpq, 1e-30)), -1., 1.)
     sin_a = jnp.clip(eps / safe_dpy, 0., 1.)
     cos_a = jnp.sqrt(1. - sin_a * sin_a)
     sin_b = jnp.sqrt(1. - cos_b * cos_b)
@@ -178,7 +182,7 @@ def _masked_prune_jnp(A, va, B, vb, p, q, eps):
     px = A - p[None, :]
     dpx = jnp.linalg.norm(px, axis=1)
     tri = dpx < sigma
-    cos_g = jnp.clip((px @ (q - p)) /
+    cos_g = jnp.clip(jnp.dot(px, q - p, precision=hi) /
                      (jnp.maximum(dpx, 1e-30) * jnp.maximum(dpq, 1e-30)), -1., 1.)
     cos_g = jnp.where(dpx == 0.0, 1.0, cos_g)   # theta(p) = 0
     ang = (cos_g < cos_lam) & ~over_pi

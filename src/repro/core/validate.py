@@ -18,6 +18,25 @@ from __future__ import annotations
 import numpy as np
 
 
+def _core_window(pts: np.ndarray, eps: float, core: np.ndarray):
+    """Lookup of the core rows whose first coordinate lies within eps
+    (plus a relative rounding margin) of a given point's: a superset of
+    that point's core eps-neighbours, so any predicate over "core points
+    within eps" evaluates identically on the window and on every core
+    point -- at O(window) instead of O(n_core) per point."""
+    cidx = np.flatnonzero(core)
+    cidx = cidx[np.argsort(pts[cidx, 0], kind="stable")]
+    x0 = pts[cidx, 0]
+    pad = float(eps) * (1.0 + 1e-6)
+
+    def near(i: int) -> np.ndarray:
+        lo = np.searchsorted(x0, pts[i, 0] - pad, side="left")
+        hi = np.searchsorted(x0, pts[i, 0] + pad, side="right")
+        return cidx[lo:hi]
+
+    return near
+
+
 def contested_border_mask(points: np.ndarray, eps: float,
                           core: np.ndarray,
                           core_labels: np.ndarray) -> np.ndarray:
@@ -30,12 +49,14 @@ def contested_border_mask(points: np.ndarray, eps: float,
     """
     pts = np.asarray(points, np.float64)
     eps2 = float(eps) ** 2
+    core = np.asarray(core, bool)
     out = np.zeros(len(pts), bool)
-    cpts = pts[core]
-    clab = np.asarray(core_labels)[core]
+    clab = np.asarray(core_labels)
+    near = _core_window(pts, eps, core)
     for i in np.flatnonzero(~core):
-        d2 = ((cpts - pts[i]) ** 2).sum(1)
-        cands = np.unique(clab[d2 <= eps2])
+        c = near(i)
+        d2 = ((pts[c] - pts[i]) ** 2).sum(1)
+        cands = np.unique(clab[c][d2 <= eps2])
         out[i] = len(cands) > 1
     return out
 
@@ -77,10 +98,11 @@ def assert_dbscan_equivalent(points: np.ndarray, eps: float, min_pts: int,
 
     # 3: border/noise sets identical
     noncore = ~core
-    for name, l in (("A", la), ("B", lb)):
-        for i in np.flatnonzero(noncore):
-            d2 = ((pts[core] - pts[i]) ** 2).sum(1)
-            has_core = (d2 <= eps2).any()
+    near = _core_window(pts, eps, core)
+    for i in np.flatnonzero(noncore):
+        c = near(i)
+        has_core = (((pts[c] - pts[i]) ** 2).sum(1) <= eps2).any()
+        for name, l in (("A", la), ("B", lb)):
             if has_core:
                 assert l[i] >= 0, f"labeling {name}: border point {i} marked noise"
             else:
@@ -88,11 +110,13 @@ def assert_dbscan_equivalent(points: np.ndarray, eps: float, min_pts: int,
 
     # 4: border assignments valid
     for name, l in (("A", la), ("B", lb)):
-        for i in np.flatnonzero(noncore & (la >= 0 if name == "A" else lb >= 0)):
-            same = core & (l == l[i])
-            if not same.any():
+        with_core = set(np.unique(l[core]).tolist())
+        for i in np.flatnonzero(noncore & (l >= 0)):
+            if l[i] not in with_core:
                 raise AssertionError(f"labeling {name}: border {i} in empty cluster")
-            d2 = ((pts[same] - pts[i]) ** 2).sum(1)
+            c = near(i)
+            c = c[l[c] == l[i]]
+            d2 = ((pts[c] - pts[i]) ** 2).sum(1)
             assert (d2 <= eps2).any(), \
                 f"labeling {name}: border {i} assigned to cluster w/o core in eps"
 

@@ -130,12 +130,11 @@ def make_cluster_step(mesh: Mesh, eps, min_pts: int, caps: ClusterCaps,
             res.report, halo=res.report.halo | ov1 | ov2)
         return glab, own_core, own_grid, report.as_vector()[None, :]
 
-    from jax.experimental.shard_map import shard_map
     spec = P(axes)
-    fn = shard_map(local_step, mesh=mesh,
-                   in_specs=(P(axes, None), spec),
-                   out_specs=(spec, spec, spec, P(axes, None)),
-                   check_rep=False)
+    fn = jax.shard_map(local_step, mesh=mesh,
+                       in_specs=(P(axes, None), spec),
+                       out_specs=(spec, spec, spec, P(axes, None)),
+                       check_vma=False)
 
     def cluster_step(points, valid):
         labels, core, point_grid, flags = fn(points, valid)
@@ -230,17 +229,17 @@ def make_staged_cluster_steps(mesh: Mesh, eps, min_pts: int,
                          gmap[me * L + jnp.maximum(own_labels, 0)],
                          -1)
 
-    from jax.experimental.shard_map import shard_map
     s1 = P(axes)
     s2 = P(axes, None)
-    halo = shard_map(halo_step, mesh=mesh, in_specs=(s2, s1),
-                     out_specs=(s2, s2, s1, s1, s1), check_rep=False)
-    local = shard_map(local_step, mesh=mesh, in_specs=(s2, s1, s2, s2),
-                      out_specs=(s1, s1, s1, s1, s1, s1, s1, s2),
-                      check_rep=False)
-    reconcile = shard_map(reconcile_step, mesh=mesh,
-                          in_specs=(s1,) * 8, out_specs=s1,
-                          check_rep=False)
+    halo = jax.shard_map(halo_step, mesh=mesh, in_specs=(s2, s1),
+                         out_specs=(s2, s2, s1, s1, s1), check_vma=False)
+    local = jax.shard_map(local_step, mesh=mesh,
+                          in_specs=(s2, s1, s2, s2),
+                          out_specs=(s1, s1, s1, s1, s1, s1, s1, s2),
+                          check_vma=False)
+    reconcile = jax.shard_map(reconcile_step, mesh=mesh,
+                              in_specs=(s1,) * 8, out_specs=s1,
+                              check_vma=False)
     return jax.jit(halo), jax.jit(local), jax.jit(reconcile)
 
 

@@ -9,8 +9,9 @@ missed cap silently truncated the result.  Now:
    grid statistics* — an O(n log n) pass that is vanishing next to the
    clustering itself: the non-empty-grid count bounds ``grid_cap``, the
    max grid occupancy bounds ``m_cap`` (core points per grid can never
-   exceed occupancy), and the stencil bound (3^d - 1, clamped to the
-   exact offset-stencil size) seeds ``k_cap``.
+   exceed occupancy), and :func:`stencil_census` walks the grid tree's
+   levels for every grid at once to count what ``frontier_cap``,
+   ``k_cap``, ``c_cap`` and ``pair_cap`` must hold.
 2. :func:`adaptive_device_dbscan` runs the jitted pipeline, reads the
    per-cap :class:`OverflowReport`, geometrically grows exactly the caps
    that overflowed, and retries.  Caps are quantized to powers of two /
@@ -37,7 +38,7 @@ from repro import obs
 from repro.core.device_dbscan import (GritCaps, DeviceDBSCANResult,
                                       OverflowReport, device_dbscan)
 from repro.core.grids import identifiers
-from repro.core.grid_tree import offset_stencil, radius
+from repro.core.grid_tree import offset_stencil
 
 
 class CapOverflowError(RuntimeError):
@@ -118,80 +119,106 @@ def _lex_rows(a: np.ndarray) -> np.ndarray:
     return a.view([("", a.dtype)] * a.shape[1]).ravel()
 
 
-def candidate_census(points: np.ndarray, eps: float, min_pts: int,
-                     point_valid: Optional[np.ndarray] = None) -> int:
-    """Exact host-side upper bound on any *small* grid's candidate
-    total: for every non-empty grid with occupancy < MinPts, the sum of
-    occupancies over its offset stencil (a superset of the grid tree's
-    exact MinDist <= eps neighbor set, so the device pipeline's
-    per-grid totals can never exceed it).  All-core grids skip the
-    candidate scan entirely, so they don't constrain ``c_cap``.
+@dataclasses.dataclass(frozen=True)
+class StencilCensus:
+    """Exact host-side counts over the offset stencil of every non-empty
+    grid -- what the device pipeline's stencil-shaped caps must hold."""
 
-    Vectorized: one ``searchsorted`` over the lex-sorted grid ids per
-    stencil offset -- O(|stencil| * G log G), vanishing next to the
-    fit."""
+    candidates: int   # max own + stencil occupancy of a *small* grid
+    frontier: int     # max live prefix ranges at one grid-tree level
+    neighbors: int    # max non-empty stencil neighbours (self excluded)
+    pairs: int        # unordered pairs of neighbouring non-empty grids
+
+
+def _lookup(keys: np.ndarray, probe: np.ndarray):
+    """(hit mask, position) of lex-row ``probe`` in sorted ``keys``."""
+    pos = np.minimum(np.searchsorted(keys, probe), len(keys) - 1)
+    return keys[pos] == probe, pos
+
+
+def stencil_census(points: np.ndarray, eps: float, min_pts: int,
+                   point_valid: Optional[np.ndarray] = None
+                   ) -> StencilCensus:
+    """Walk the grid tree's levels on the host, every grid at once.
+
+    At level ``j`` the device traversal of a query grid keeps one range
+    per distinct ``(j+1)``-prefix of the grid identifiers whose partial
+    offset from the query's prefix is below d; the last level's ranges
+    are the query's non-empty stencil cells (itself included).  Counting
+    those prefixes per query gives ``frontier_cap`` and ``k_cap`` exactly
+    (the device sees the same partition up to float32 identifier
+    rounding), and the neighbour counts bound the core-grid merge pairs.
+    ``candidates`` sums occupancies over the stencil of every grid with
+    occupancy < MinPts -- a superset of the device's MinDist neighbour
+    set, so it bounds every small grid's candidate total; all-core grids
+    skip the candidate scan and don't constrain ``c_cap``.
+
+    Vectorized: one ``searchsorted`` over the lex-sorted prefixes per
+    stencil delta and level -- O(|stencil| * G log G), vanishing next to
+    the fit."""
     pts = np.asarray(points, np.float64)
     if point_valid is not None:
         pts = pts[np.asarray(point_valid, bool)]
     if len(pts) == 0:
-        return 1
+        return StencilCensus(1, 1, 0, 0)
     d = pts.shape[1]
     ids, _, _ = identifiers(pts, eps)
     uids, counts = np.unique(np.asarray(ids, np.int64), axis=0,
                              return_counts=True)
+    deltas = np.asarray(offset_stencil(d)[0], np.int64)
+    frontier = 1
+    for j in range(d):
+        pre = np.unique(uids[:, :j + 1], axis=0)        # lex-sorted
+        keys = _lex_rows(pre)
+        live = np.zeros(len(pre), np.int64)
+        for delta in np.unique(deltas[:, :j + 1], axis=0):
+            live += _lookup(keys, _lex_rows(pre + delta))[0]
+        frontier = max(frontier, int(live.max()))
+    # the last level's prefixes are the grids themselves (uids order)
+    nbrs = live - 1
     small = counts < min_pts
-    if not small.any():
-        return 1
-    keys = _lex_rows(uids)                       # sorted (np.unique)
-    totals = np.zeros(int(small.sum()), np.int64)
-    deltas, _ = offset_stencil(d)
-    for delta in np.asarray(deltas, np.int64):
-        probe = _lex_rows(uids[small] + delta)
-        pos = np.searchsorted(keys, probe)
-        pos = np.minimum(pos, len(keys) - 1)
-        hit = keys[pos] == probe
-        totals += np.where(hit, counts[pos], 0)
-    return int(totals.max())
+    cand = 1
+    if small.any():
+        keys = _lex_rows(uids)
+        totals = np.zeros(int(small.sum()), np.int64)
+        for delta in deltas:
+            hit, pos = _lookup(keys, _lex_rows(uids[small] + delta))
+            totals += np.where(hit, counts[pos], 0)
+        cand = int(totals.max())
+    return StencilCensus(candidates=cand, frontier=frontier,
+                         neighbors=int(nbrs.max()),
+                         pairs=int(nbrs.sum()) // 2)
 
 
 def _caps_from_stats(n: int, d: int, num_grids: int, max_occ: int,
-                     cand_max: int, margin: float, extra_grids: int,
-                     use_kernels: bool) -> GritCaps:
-    """``GritCaps`` from (grid count, max occupancy, max small-grid
-    candidate total) -- the quantization/clamp discipline shared by the
-    global and the per-shard estimators."""
+                     census: StencilCensus, margin: float,
+                     extra_grids: int, use_kernels: bool) -> GritCaps:
+    """``GritCaps`` from grid statistics and the stencil census -- the
+    quantization/clamp discipline shared by the global and the
+    per-shard estimators.  ``margin`` pads the counted caps so the few
+    cells float32 identifiers round differently cannot overflow them."""
     grid_cap = _pow2_at_least(
         int(math.ceil(num_grids * margin)) + extra_grids, lo=8)
     grid_block = min(64, grid_cap)
 
-    # 3^d - 1 stencil heuristic, clamped to the exact offset-stencil
-    # size (the provable per-grid neighbor maximum); at low d the exact
-    # bound is small enough to just provision outright
-    bound = stencil_neighbor_bound(d)
-    k_est = bound if bound <= 32 else max(3 ** d - 1, 8)
-    k_cap = _mult8(min(k_est, bound, max(grid_cap - 1, 1)))
+    # the census counts neighbours exactly; the offset-stencil size is
+    # the provable per-grid maximum
+    k_cap = _mult8(min(int(math.ceil(census.neighbors * margin)),
+                       stencil_neighbor_bound(d), max(grid_cap - 1, 1)))
+    frontier_cap = _pow2_at_least(int(math.ceil(census.frontier * margin)),
+                                  lo=8)
 
     m_cap = _mult8(max_occ)
     # candidate list of a small grid: the census is the exact stencil
     # occupancy sum, an upper bound on what the device's (possibly
     # tighter) MinDist neighbor set can produce
-    c_cap = _pow2_at_least(min(n, cand_max), lo=32)
+    c_cap = _pow2_at_least(min(n, census.candidates), lo=32)
 
-    # deduped (g < g') merge pairs are bounded by G * k / 2; density
-    # rarely reaches it, but a half-bound start avoids a recompile on
-    # blob-like data where most neighbor pairs are core-core
-    pair_cap = _pow2_at_least(num_grids * k_cap // 2 + 8, lo=64)
+    # deduped (g < g') merge pairs are core-grid neighbour pairs: at
+    # most every neighbouring pair of non-empty grids
+    pair_cap = _pow2_at_least(int(math.ceil(census.pairs * margin)) + 8,
+                              lo=64)
     pair_block = min(256, pair_cap)
-
-    # the per-level surviving prefix count depends on the id
-    # distribution, not just geometry; the r^(d-1) fanout regularly
-    # undershoots by one pow2 step on blob-like data, and a too-small
-    # frontier costs a full overflow fit + retry on EVERY caps=None
-    # call -- double it up front (a [frontier_cap] working set, so the
-    # headroom is nearly free)
-    r = 2 * radius(d) + 1
-    frontier_cap = _pow2_at_least(
-        2 * min(int(r ** max(d - 1, 1)), 256), lo=32)
 
     # paper Theorem 3: FastMerging terminates within |s_i| + |s_j|
     # iterations; lax.while_loop makes a generous bound free at runtime
@@ -219,8 +246,8 @@ def estimate_caps(points: np.ndarray, eps: float, min_pts: int,
     pts = np.asarray(points)
     n, d = pts.shape
     num_grids, max_occ = grid_stats(pts, eps, point_valid)
-    cand_max = candidate_census(pts, eps, min_pts, point_valid)
-    return _caps_from_stats(n, d, num_grids, max_occ, cand_max,
+    census = stencil_census(pts, eps, min_pts, point_valid)
+    return _caps_from_stats(n, d, num_grids, max_occ, census,
                             margin, extra_grids, use_kernels)
 
 
@@ -266,7 +293,7 @@ def estimate_shard_caps(points: np.ndarray, eps: float, min_pts: int,
     *shard's* grid count is roughly ``1 / n_shards`` of the global one,
     yet shard-max caps derived globally inflate every shard to the
     whole dataset's table.  This runs :func:`grid_stats` /
-    :func:`candidate_census` per shard over the exact per-shard point
+    :func:`stencil_census` per shard over the exact per-shard point
     set (own slab + the neighbors' 2*eps ghost bands) and takes the max
     over shards -- still one shared static shape for the SPMD step,
     but sized to the worst shard instead of the union."""
@@ -276,13 +303,16 @@ def estimate_shard_caps(points: np.ndarray, eps: float, min_pts: int,
         return estimate_caps(pts, eps, min_pts, margin=margin,
                              extra_grids=extra_grids,
                              use_kernels=use_kernels)
-    num_grids, max_occ, cand_max, n_max = 1, 1, 1, 1
+    num_grids, max_occ, n_max = 1, 1, 1
+    census = StencilCensus(1, 1, 0, 0)
     for sub in _shard_point_sets(pts, eps, n_shards):
         g, o = grid_stats(sub, eps)
-        c = candidate_census(sub, eps, min_pts)
+        c = stencil_census(sub, eps, min_pts)
         num_grids, max_occ = max(num_grids, g), max(max_occ, o)
-        cand_max, n_max = max(cand_max, c), max(n_max, len(sub))
-    return _caps_from_stats(n_max, d, num_grids, max_occ, cand_max,
+        n_max = max(n_max, len(sub))
+        census = StencilCensus(*(max(a, b) for a, b in zip(
+            dataclasses.astuple(census), dataclasses.astuple(c))))
+    return _caps_from_stats(n_max, d, num_grids, max_occ, census,
                             margin, extra_grids, use_kernels)
 
 

@@ -298,7 +298,7 @@ def grid_components(num_grids: int,
 
     Vectorized min-label propagation with pointer jumping (the host
     twin of ``repro.core.labels.label_propagation``): O(E) work per
-    round, O(log G) rounds.  Returns [G] component representative =
+    round, rounds until a fixpoint.  Returns [G] component representative =
     smallest grid index in the component (isolated grids map to self).
     """
     lab = np.arange(num_grids, dtype=np.int64)

@@ -12,8 +12,10 @@ with host code reduced to packing flat int32 gather indices and
 running the segmented reduceat reductions (DESIGN.md §7: on CPU one C
 pass beats XLA's scatter-based segment ops).  Stages whose flat
 element count falls under the adaptive gates (``MIN_FLAT_T`` /
-``EDGE_MIN_FLAT_T``) run their host float64 twin outright -- pure
-performance routing, the twin is the reference.
+``EDGE_MIN_FLAT_T``) or above the host-packing bound ``MAX_FLAT_T``
+run their host float64 twin outright -- pure performance and memory
+routing, the twin is the reference.  Dispatches go in ``FLAT_CHUNK``
+element pieces so device temporaries stay bounded at any stage size.
 
 **Bit-exactness by guard band** (DESIGN.md §6/§7).  GriT-DBSCAN's value
 is *exact* DBSCAN, so the float32 kernels never get the last word.
@@ -144,19 +146,48 @@ MIN_FLAT_T = 1 << 15
 # kernel always pays the full cross product -- so its crossover sits
 # far higher than the count-every-pair stages above
 EDGE_MIN_FLAT_T = 1 << 20
+# flat elements per kernel dispatch: a larger stage is split into
+# chunks of this size, all sharing one jit key, so the device's
+# temporaries stay bounded (~0.5 GB per dispatch on a TPU v5e)
+FLAT_CHUNK = 1 << 24
+# above this many flat elements a stage runs its host float64 twin:
+# the flat layout is packed on the host at tens of bytes per element,
+# and cross products this large are whole-index passes (the first
+# mutation's merge-graph build, a delete spread over every grid) where
+# the twin's per-group loop -- and the edge decider's early exit --
+# keep host memory bounded
+MAX_FLAT_T = 1 << 26
+
+
+def _flat_bucket(T: int) -> int:
+    """Padded flat length: the pow2 bucket up to ``FLAT_CHUNK``, whole
+    chunks beyond it (one jit key per bucket either way)."""
+    if T <= FLAT_CHUNK:
+        return _pow2_at_least(T, lo=8)
+    return -(-T // FLAT_CHUNK) * FLAT_CHUNK
+
+
+def _dispatch_chunks(stage: str, T: int, tcap: int, run):
+    """Run ``run(lo, hi)`` over ``[0, tcap)`` in ``FLAT_CHUNK`` pieces
+    and return the concatenated device result."""
+    step = min(tcap, FLAT_CHUNK)
+    parts = []
+    for lo in range(0, tcap, step):
+        obs.note_flat_dispatch(stage, max(0, min(T, lo + step) - lo), step)
+        parts.append(run(lo, lo + step))
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts)
 
 
 def _d2_flat_res(ds, ra: np.ndarray, rb: np.ndarray, gg: np.ndarray,
                  anch32: np.ndarray):
-    """Dispatch one flat resident-pair distance kernel.  Anchors are
+    """Dispatch the flat resident-pair distance kernel.  Anchors are
     gathered per element on the host so the upload shapes -- and hence
-    the jit key -- depend on the single pow2 T bucket, not on the group
+    the jit key -- depend on the single T bucket, not on the group
     count: the bucket set saturates within a few waves and recompiles
     stop.  Returns the device array; the caller blocks with
     ``np.asarray`` and slices ``[:len(ra)]``."""
     T = len(ra)
-    tcap = _pow2_at_least(T, lo=8)
-    obs.note_flat_dispatch("res", T, tcap)
+    tcap = _flat_bucket(T)
     ra_p = np.empty(tcap, np.int32)       # tail-fill only: the pads
     ra_p[:T] = ra                         # alias row 0 / anchor 0 and
     ra_p[T:] = 0                          # their distances are sliced
@@ -166,9 +197,10 @@ def _d2_flat_res(ds, ra: np.ndarray, rb: np.ndarray, gg: np.ndarray,
     av_p = np.empty((tcap, anch32.shape[1]), np.float32)
     av_p[:T] = anch32[gg]
     av_p[T:] = 0.0
-    return kernel_ops.pairwise_d2_flat_res(
-        ds.points_res, jnp.asarray(ra_p), jnp.asarray(rb_p),
-        jnp.asarray(av_p))
+    return _dispatch_chunks(
+        "res", T, tcap, lambda lo, hi: kernel_ops.pairwise_d2_flat_res(
+            ds.points_res, jnp.asarray(ra_p[lo:hi]),
+            jnp.asarray(rb_p[lo:hi]), jnp.asarray(av_p[lo:hi])))
 
 
 class _Timer:
@@ -388,9 +420,15 @@ def predict_device_async(index, ds, q: np.ndarray,
     csz = cand_per[group_of]                      # candidates per query
     offs = cand_offs[group_of]
     T = int(csz.sum())
+    if T > MAX_FLAT_T:
+        # host twin (module docstring): same labels and d2 bit for bit
+        tm.mark("t_pack")
+        out, out_d2 = index._predict_host(q, 2048, stats)
+        tm.mark("t_kernel")
+        return lambda: (out, out_d2)
     rr_flat = rows[_expand(offs, csz)]
     qo_flat = np.repeat(np.arange(m), csz)        # sorted segment ids
-    tcap = _pow2_at_least(T, lo=8)
+    tcap = _flat_bucket(T)
     mcap = _pow2_at_least(m + 1, lo=8)            # +1: pad segment
     rr_p = np.zeros(tcap, np.int32)
     rr_p[:T] = rr_flat
@@ -401,12 +439,13 @@ def predict_device_async(index, ds, q: np.ndarray,
     # anchors host-gathered per element: jit key = (tcap, mcap) only
     av_p = np.zeros((tcap, index.d), np.float32)
     av_p[:T] = np.repeat(anch32[group_of], csz, axis=0)
-    obs.note_flat_dispatch("predict", T, tcap)
-    d2dev = kernel_ops.pairwise_d2_flat(
-        ds.points_res, jnp.asarray(qa_p), jnp.asarray(rr_p),
-        jnp.asarray(qo_p), jnp.asarray(av_p))
+    qa_dev = jnp.asarray(qa_p)
+    d2dev = _dispatch_chunks(
+        "predict", T, tcap, lambda lo, hi: kernel_ops.pairwise_d2_flat(
+            ds.points_res, qa_dev, jnp.asarray(rr_p[lo:hi]),
+            jnp.asarray(qo_p[lo:hi]), jnp.asarray(av_p[lo:hi])))
     if stats is not None:
-        stats["chunks"] = 1
+        stats["chunks"] = -(-tcap // FLAT_CHUNK)
     tm.mark("t_pack")
 
     def resolve():
@@ -548,6 +587,13 @@ def recompute_cores_device(index, ds, affected: np.ndarray,
         # need filter guarantees live_counts < MinPts here: demote all
         flip[np.isin(cand_g, zero)] = True
     kern = kern[(nb_sizes[kern] > 0) & (cand_sizes[kern] > 0)]
+    if int(cand_sizes[kern] @ nb_sizes[kern]) > MAX_FLAT_T:
+        tm.mark("t_pack")
+        flips = _recompute_cores_host(index, affected, direction, ctr)
+        if len(flips):
+            ds.flip_core(flips, direction > 0)
+        tm.mark("t_kernel")
+        return flips
     base_of = live_counts[need]
     anch32 = _anchors(index, ds, index.ids[need])
     if len(kern):
@@ -625,10 +671,12 @@ def decide_edges_device(index, ds, pairs: np.ndarray,
     # a pair with no core on either side has pairmin inf: no edge,
     # certain (the host reduce over an empty set agrees)
     psel = np.flatnonzero((sizes_a > 0) & (sizes_b > 0))
-    if int(sizes_a[psel] @ sizes_b[psel]) < EDGE_MIN_FLAT_T:
+    if not EDGE_MIN_FLAT_T <= int(sizes_a[psel] @ sizes_b[psel]) \
+            <= MAX_FLAT_T:
         # small decision batch: the host twin's per-pair early exit
-        # beats the full-cross-product dispatch (gate before any flat
-        # layout is built; same-output by construction)
+        # beats the full-cross-product dispatch; a huge one would not
+        # fit the host packing (gate before any flat layout is built;
+        # same-output by construction)
         tm.mark("t_pack")
         hit[rem] = _decide_edges_batch(index, pairs[rem], ctr)
         tm.mark("t_kernel")
@@ -704,8 +752,9 @@ def border_pass_device(index, ds, rows: np.ndarray,
     # from one cumsum over the (cheap) per-grid core counts
     gcc = np.concatenate([[0], np.cumsum(ccounts[gflat])])
     sizes_b = gcc[g_offs + gsz] - gcc[g_offs]
-    if int(sizes_a @ sizes_b) < MIN_FLAT_T:
-        # tiny border batch: host twin beats dispatch overhead
+    if not MIN_FLAT_T <= int(sizes_a @ sizes_b) <= MAX_FLAT_T:
+        # tiny border batch: host twin beats dispatch overhead; a huge
+        # one would not fit the host packing
         tm.mark("t_pack")
         _border_pass_host(index, rows, grid_of, ctr)
         tm.mark("t_kernel")

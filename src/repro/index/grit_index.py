@@ -54,6 +54,11 @@ _SNAPSHOT_VERSION = 2
 _ACCEPTED_VERSIONS = (1, 2)
 
 
+# candidate slots (groups x cand_cap) per kernel predict call: the
+# fit's own per-call size (grid_block 64 x c_cap 16384 at paper scale)
+PREDICT_SLOTS = 1 << 20
+
+
 @dataclasses.dataclass
 class PredictCaps:
     """Static shapes of the batched kernel predict path.
@@ -552,41 +557,55 @@ class GritIndex:
             stats.update(groups=B, candidates=int(len(rows)),
                          caps=dataclasses.asdict(pc), caps_grew=grew)
 
-        a = np.zeros((pc.group_cap, pc.query_cap, self.d), np.float64)
-        b = np.zeros((pc.group_cap, pc.cand_cap, self.d), np.float64)
-        vb = np.zeros((pc.group_cap, pc.cand_cap), bool)
-        brow = np.zeros((pc.group_cap, pc.cand_cap), np.int64)
-        # scatter queries into their group's slot row (same flat-offset
-        # pattern as the candidate scatter below)
+        # groups per kernel call: the TPU kernel pads every candidate
+        # slot to the 128-lane width, so one call over all group slots
+        # can outgrow device memory (4096 cells x 8192 candidates would
+        # be 17 GB); ``PREDICT_SLOTS`` candidate slots per call bound
+        # it, every call of the batch shares one jit key, and the host
+        # packs one call's slots at a time
+        chunk = min(pc.group_cap, max(1, PREDICT_SLOTS // pc.cand_cap))
+        # re-center on the integer lattice point at or below each
+        # group's cell origin (float64 subtract, then cast --
+        # stencil-scale coordinates for the f32 kernel; integer-valued
+        # coordinates re-center exactly, so their f32 distances are
+        # the float64 ones)
+        anchor = np.floor(self.mins[None, :]
+                          + (rep_ids - self.id_shift[None, :]) * self.side)
+        # slot of each query (sorted order) and candidate in its group
         qgroup = np.repeat(np.arange(B, dtype=np.int64), gcount)
         qslot = np.arange(m, dtype=np.int64) - np.repeat(gstart, gcount)
-        a[qgroup, qslot] = q[qorder]
         qslot_of = np.empty(m, np.int64)      # flat slot of each query
         qslot_of[qorder] = qgroup * pc.query_cap + qslot
         cbase = np.cumsum(cand_per) - cand_per
         slot = np.arange(len(rows)) - np.repeat(cbase, cand_per)
-        b[g_of, slot] = self.points[rows]
-        vb[g_of, slot] = True
-        brow[g_of, slot] = rows
-        # re-center on each group's cell origin (float64 subtract, then
-        # cast -- stencil-scale coordinates for the f32 kernel)
-        anchor = (self.mins[None, :]
-                  + (rep_ids - self.id_shift[None, :]) * self.side)
-        anchor = np.concatenate(
-            [anchor, np.zeros((pc.group_cap - B, self.d))])[:, None, :]
-        dmin, argi = kernel_ops.row_min_batch(
-            jnp.asarray(a - anchor, jnp.float32),
-            jnp.asarray(b - anchor, jnp.float32),
-            valid_b=jnp.asarray(vb))
-        # grit-lint: disable=hot-path-sync -- the predict kernel's intended block point: both reductions resolve in one transfer
-        dmin = np.asarray(dmin).reshape(-1)
-        argi = np.asarray(argi).reshape(-1)  # grit-lint: disable=hot-path-sync -- same block point as dmin above
+        qcut = np.append(gstart, m)
+        ccut = np.append(cbase, len(rows))
+        parts = []
+        for s in range(0, B, chunk):
+            e = min(s + chunk, B)
+            qs = slice(qcut[s], qcut[e])
+            cs = slice(ccut[s], ccut[e])
+            a = np.zeros((chunk, pc.query_cap, self.d), np.float32)
+            b = np.zeros((chunk, pc.cand_cap, self.d), np.float32)
+            vb = np.zeros((chunk, pc.cand_cap), bool)
+            a[qgroup[qs] - s, qslot[qs]] = (q[qorder[qs]]
+                                           - anchor[qgroup[qs]])
+            b[g_of[cs] - s, slot[cs]] = (self.points[rows[cs]]
+                                         - anchor[g_of[cs]])
+            vb[g_of[cs] - s, slot[cs]] = True
+            parts.append(kernel_ops.row_min_batch(
+                jnp.asarray(a), jnp.asarray(b), valid_b=jnp.asarray(vb)))
+        if stats is not None:
+            stats["chunks"] = len(parts)
+        # grit-lint: disable=hot-path-sync -- the predict kernel's intended block point: every chunk was dispatched above
+        dmin = np.concatenate([np.asarray(d) for d, _ in parts]).reshape(-1)
+        argi = np.concatenate([np.asarray(i) for _, i in parts]).reshape(-1)  # grit-lint: disable=hot-path-sync -- same block point as dmin above
         out = np.full(m, -1, np.int64)
         dq = dmin[qslot_of]
         aq = argi[qslot_of]
         hit = (dq <= eps2) & (aq >= 0)
         gq = qslot_of // pc.query_cap
-        out[hit] = self.labels[brow[gq[hit], aq[hit]]]
+        out[hit] = self.labels[rows[cbase[gq[hit]] + aq[hit]]]
         out_d2 = np.where(aq >= 0, dq.astype(np.float64), np.inf)
         return out, out_d2
 

@@ -455,8 +455,14 @@ def _row_min2_batch_jit(a, b, valid_b, *, block_m, block_n, interpret):
 
 @jax.jit
 def _pairwise_d2_flat_jit(points_res, qa, rr, qo, av):
-    diff = (points_res[rr] - av) - qa[qo]
-    return jnp.sum(diff * diff, axis=1)
+    # one 1-D gather per coordinate: gathering [T, d] rows makes the TPU
+    # lay each out lane-padded, 8.9 GB of temporaries at T = 2^24
+    # against 0.5 GB this way
+    d2 = jnp.zeros(rr.shape, jnp.float32)
+    for k in range(points_res.shape[1]):
+        diff = (points_res[:, k][rr] - av[:, k]) - qa[:, k][qo]
+        d2 = d2 + diff * diff
+    return d2
 
 
 def pairwise_d2_flat(points_res, qa, rr, qo, av):

@@ -37,10 +37,16 @@ LANE = 128
 
 
 def _sq_dist_tile(a, b):
-    """[BM, D] x [BN, D] -> [BM, BN] squared distances (f32, MXU dot)."""
+    """[BM, D] x [BN, D] -> [BM, BN] squared distances (f32, MXU dot).
+
+    The contraction runs at ``Precision.HIGHEST`` (full f32 passes on
+    the MXU): at the default precision the TPU rounds the operands to
+    bf16, whose 8-bit mantissa cannot hold stencil-scale coordinates,
+    so eps predicates would flip far outside any f32 error band."""
     a = a.astype(jnp.float32)
     b = b.astype(jnp.float32)
     ab = jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                             precision=jax.lax.Precision.HIGHEST,
                              preferred_element_type=jnp.float32)
     aa = jnp.sum(a * a, axis=1, keepdims=True)        # [BM, 1]
     bb = jnp.sum(b * b, axis=1, keepdims=True).T      # [1, BN]

@@ -25,7 +25,7 @@ def sq_dists(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
     b = b.astype(jnp.float32)
     aa = jnp.sum(a * a, axis=1)[:, None]
     bb = jnp.sum(b * b, axis=1)[None, :]
-    d2 = aa + bb - 2.0 * (a @ b.T)
+    d2 = aa + bb - 2.0 * jnp.dot(a, b.T, precision=jax.lax.Precision.HIGHEST)
     return jnp.maximum(d2, 0.0)
 
 
@@ -71,6 +71,7 @@ def sq_dists_batch(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
     aa = jnp.sum(a * a, axis=-1)[:, :, None]
     bb = jnp.sum(b * b, axis=-1)[:, None, :]
     ab = jnp.einsum("bmd,bnd->bmn", a, b,
+                    precision=jax.lax.Precision.HIGHEST,
                     preferred_element_type=jnp.float32)
     return jnp.maximum(aa + bb - 2.0 * ab, 0.0)
 

@@ -150,7 +150,6 @@ def moe_forward_shardmap_ep(cfg: LMConfig, p: dict, x: jnp.ndarray,
 
     Requires E % n_data == 0 and ff % n_model == 0.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     m = cfg.moe
@@ -218,14 +217,14 @@ def moe_forward_shardmap_ep(cfg: LMConfig, p: dict, x: jnp.ndarray,
         y = jax.lax.psum(y, model_axis)                # sum ff slices
         return y.reshape(B_loc, S, d), aux
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(batch_axes, None, None), P(None, None),
                   P(batch_axes, None, model_axis),
                   P(batch_axes, None, model_axis),
                   P(batch_axes, model_axis, None)),
         out_specs=(P(batch_axes, None, None), P()),
-        check_rep=False)
+        check_vma=False)
     return fn(x, p["router"],
               p["w_gate"].astype(x.dtype), p["w_up"].astype(x.dtype),
               p["w_down"].astype(x.dtype))
@@ -255,7 +254,6 @@ def moe_forward_shardmap(cfg: LMConfig, p: dict, x: jnp.ndarray,
     global-capacity reference; equivalence at high capacity_factor is
     tested.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     m = cfg.moe
@@ -341,13 +339,13 @@ def moe_forward_shardmap(cfg: LMConfig, p: dict, x: jnp.ndarray,
         y = jax.lax.psum(y, model_axis)
         return y.reshape(B_loc, S, d), aux
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(batch_axes, None, None), P(None, None),
                   P(model_axis, None, None), P(model_axis, None, None),
                   P(model_axis, None, None)),
         out_specs=(P(batch_axes, None, None), P()),
-        check_rep=False)
+        check_vma=False)
     return fn(x, router, wg, wu, wd)
 
 

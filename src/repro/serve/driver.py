@@ -457,8 +457,11 @@ def main() -> None:
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
+    from repro import compile_cache
     from repro.data.scenarios import get_scenario
     from repro.engine import cluster
+
+    compile_cache.enable()
 
     sc = get_scenario(args.scenario)
     pts = sc.points(seed=args.seed)

@@ -9,6 +9,7 @@ from repro.core.dbscan import grit_dbscan, brute_dbscan
 from repro.core.device_dbscan import device_dbscan, GritCaps, PAD_COORD
 from repro.core.validate import assert_dbscan_equivalent
 from repro.core.grids import build_grids, build_grids_device, PAD_ID
+from repro.core.labels import label_propagation
 
 
 @pytest.mark.parametrize("d", [2, 3, 5, 7])
@@ -32,6 +33,31 @@ def test_engine_matrix_equivalent(variant, neighbor_engine, merge_engine):
                     neighbor_engine=neighbor_engine,
                     merge_engine=merge_engine)
     assert_dbscan_equivalent(pts, eps, min_pts, ref, r.labels)
+
+
+@pytest.mark.parametrize("kind", ["permuted", "zigzag"])
+def test_label_propagation_converges_on_long_chains(kind):
+    """Chains whose node numbering defeats pointer jumping need O(N)
+    rounds (84 and 133 at N = 256, against a log2 N + 2 = 10 round cap
+    the loop once had): the labels must still be the component minima.
+    Two disjoint chains plus an invalid node pin the per-component min."""
+    n = 256
+    rng = np.random.default_rng(0)
+    if kind == "permuted":
+        order = rng.permutation(n)
+    else:
+        order = np.concatenate([np.arange(0, n, 2), np.arange(1, n, 2)[::-1]])
+    half = n // 2
+    chains = [order[:half], order[half:]]
+    edges = np.concatenate([np.stack([c[:-1], c[1:]], 1) for c in chains])
+    node_valid = np.ones(n + 1, bool)
+    node_valid[n] = False                 # isolated, invalid node
+    lab = np.asarray(label_propagation(
+        n + 1, jnp.asarray(edges, jnp.int32),
+        jnp.ones(len(edges), bool), jnp.asarray(node_valid)))
+    for c in chains:
+        assert (lab[c] == c.min()).all()
+    assert lab[n] == n + 1
 
 
 def test_kappa_small_like_paper():

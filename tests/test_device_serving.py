@@ -192,6 +192,34 @@ def test_adaptive_gates_differential():
     assert np.array_equal(lh, ld) and np.array_equal(dh, dd)
 
 
+@pytest.mark.parametrize("route", ["chunked", "host-routed"])
+def test_chunked_and_host_routed_stages_differential(route, monkeypatch):
+    """Stage sizes the catalogue cannot reach at CI scale: flat stages
+    split into several ``FLAT_CHUNK`` dispatches, or routed whole to
+    their host twin above ``MAX_FLAT_T``.  Both stay bit-identical."""
+    if route == "chunked":
+        monkeypatch.setattr(device_state, "FLAT_CHUNK", 256)
+    else:
+        monkeypatch.setattr(device_state, "MAX_FLAT_T", 0)
+    sc = get_churn_scenario("churn-split-2d")
+    pts = sc.fit_points()
+    host, dev = _fit_pair(pts, sc.base.eps, sc.base.min_pts)
+    chunks = []
+    for i, (op, arg) in enumerate(sc.ops(0)):
+        sh, sd = (host.insert(arg), dev.insert(arg)) if op == "insert" \
+            else (host.delete(arg), dev.delete(arg))
+        _assert_stats_match(sh, sd, (route, op, i))
+        _assert_state_match(host, dev, (route, op, i))
+        q = pts[i::7]
+        stats = {}
+        lh, dh = host.predict(q, mode="host", return_d2=True)
+        ld, dd = dev.predict(q, mode="device", return_d2=True, stats=stats)
+        assert np.array_equal(lh, ld) and np.array_equal(dh, dd), (route, i)
+        chunks.append(stats.get("chunks", 0))
+    if route == "chunked":
+        assert max(chunks) > 1
+
+
 def test_delete_split_differential():
     """An explicit bridge-cut: deleting the bridge points must split
     the cluster identically on both paths (the non-monotone case the

@@ -104,6 +104,25 @@ def test_predict_kernel_mode_matches_host(name, fitted):
     np.testing.assert_array_equal(host[decidable], kern[decidable])
 
 
+def test_predict_kernel_mode_split_calls_match_one_call(fitted,
+                                                        monkeypatch):
+    """A batch whose candidate slots exceed ``PREDICT_SLOTS`` runs as
+    several kernel calls of one shape; the answers are those of the
+    single call."""
+    from repro.index import grit_index
+    ss, pts, res = fitted("query-heavy-3d")
+    idx = res.index
+    q = ss.query_batch()
+    one = {}
+    want = idx.predict(q, mode="kernel", stats=one)
+    assert one["chunks"] == 1
+    monkeypatch.setattr(grit_index, "PREDICT_SLOTS", idx.predict_caps.cand_cap)
+    split = {}
+    got = idx.predict(q, mode="kernel", stats=split)
+    assert split["chunks"] == split["groups"] > 1
+    np.testing.assert_array_equal(got, want)
+
+
 def test_predict_empty_grid_and_far_queries(fitted):
     ss, pts, res = fitted("query-heavy-3d")
     idx = res.index

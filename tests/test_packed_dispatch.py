@@ -18,6 +18,7 @@ import dataclasses
 
 import numpy as np
 import pytest
+import jax
 import jax.numpy as jnp
 
 from repro import obs
@@ -25,7 +26,7 @@ from repro.data.scenarios import default_scenarios, scenario_map
 from repro.core.device_dbscan import GritCaps, device_dbscan
 from repro.core.grids import build_grids_device
 from repro.core.grid_tree import device_neighbor_table
-from repro.engine import (adaptive_device_dbscan, candidate_census,
+from repro.engine import (adaptive_device_dbscan, stencil_census,
                           cluster, estimate_caps, estimate_shard_caps)
 
 SCENARIOS = scenario_map()
@@ -240,16 +241,19 @@ def test_restore_accepts_pre_packed_snapshots():
 
 def test_candidate_census_bounds_device_totals():
     """The census is the stencil occupancy sum -- an upper bound on the
-    device's (MinDist-pruned) per-grid candidate totals, so census-sized
-    c_cap can never overflow on the fit that sized it."""
+    device's (MinDist-pruned) per-grid candidate totals -- plus the
+    grid tree's exact per-level frontier and neighbour counts, so
+    census-sized caps never overflow on the fit that sized them."""
     rng = np.random.default_rng(31)
     pts = np.asarray(rng.uniform(0, 60.0, size=(600, 2)), np.float32)
     eps, min_pts = 4.0, 6
-    cmax = candidate_census(pts, eps, min_pts)
+    census = stencil_census(pts, eps, min_pts)
     caps = estimate_caps(pts, eps, min_pts)
-    assert caps.c_cap >= cmax
+    assert caps.c_cap >= census.candidates
+    assert caps.k_cap >= census.neighbors
+    assert caps.frontier_cap >= census.frontier
     res = device_dbscan(jnp.asarray(pts), eps, min_pts, caps)
-    assert not bool(res.report.candidates)
+    assert jax.device_get(res.report).overflowing() == ()
 
 
 def test_estimate_shard_caps_not_inflated_to_global():
