@@ -250,10 +250,15 @@ def device_dbscan(points: jnp.ndarray, eps, min_pts: int, caps: GritCaps,
     pts = jnp.where(point_valid[:, None], points, PAD_COORD)
 
     # ---- step 1: grids + grid tree neighbors --------------------------
-    dg = build_grids_device(pts, eps, caps.grid_cap)
-    nbr, nbr_off, ovf_frontier, ovf_k = device_neighbor_table(
-        dg.ids, dg.num_grids, frontier_cap=caps.frontier_cap,
-        k_cap=caps.k_cap, include_self=False, packed=caps.packed)
+    # every phase runs under a ``grit.*`` named scope (the packed tier
+    # sweeps under ``<phase>/tier<k>``): HLO op_name metadata that trace
+    # viewers and HLO dumps read; codegen and the jit key are unchanged
+    with jax.named_scope("grit.grids"):
+        dg = build_grids_device(pts, eps, caps.grid_cap)
+    with jax.named_scope("grit.neighbors"):
+        nbr, nbr_off, ovf_frontier, ovf_k = device_neighbor_table(
+            dg.ids, dg.num_grids, frontier_cap=caps.frontier_cap,
+            k_cap=caps.k_cap, include_self=False, packed=caps.packed)
     G = caps.grid_cap
     live = jnp.arange(G, dtype=jnp.int32) < dg.num_grids
     sorted_valid = point_valid[dg.order]
@@ -261,260 +266,270 @@ def device_dbscan(points: jnp.ndarray, eps, min_pts: int, caps: GritCaps,
     spts = dg.sorted_points
 
     # ---- step 2: core points ------------------------------------------
-    # all-core shortcut: grids with >= MinPts (valid) points
-    valid_counts = jnp.zeros((G,), jnp.int32).at[dg.point_grid].add(
-        sorted_valid.astype(jnp.int32))
-    big = (valid_counts >= min_pts) & live
-    core_sorted = big[dg.point_grid] & sorted_valid
-    # grids holding only padding points (all invalid points share
-    # PAD_COORD, so they land in grids of their own) need no core scan
-    # and must not count against c_cap
-    occupied = live & (valid_counts > 0)
+    with jax.named_scope("grit.core"):
+        # all-core shortcut: grids with >= MinPts (valid) points
+        valid_counts = jnp.zeros((G,), jnp.int32).at[dg.point_grid].add(
+            sorted_valid.astype(jnp.int32))
+        big = (valid_counts >= min_pts) & live
+        core_sorted = big[dg.point_grid] & sorted_valid
+        # grids holding only padding points (all invalid points share
+        # PAD_COORD, so they land in grids of their own) need no core scan
+        # and must not count against c_cap
+        occupied = live & (valid_counts > 0)
 
-    p_cap = max(min_pts - 1, 1)
+        p_cap = max(min_pts - 1, 1)
 
-    def grid_anchor(gsel):
-        """First own point of each selected grid: the re-centering origin
-        for the kernelized distance plane (module docstring)."""
-        return spts[jnp.minimum(dg.starts[gsel], n - 1)][:, None, :]
+        def grid_anchor(gsel):
+            """First own point of each selected grid: the re-centering origin
+            for the kernelized distance plane (module docstring)."""
+            return spts[jnp.minimum(dg.starts[gsel], n - 1)][:, None, :]
 
-    # per-grid candidate totals (own + neighbor occupancies): the same
-    # numbers _candidates_for_grids derives per block, computed once for
-    # every grid -- they drive the candidates overflow flag and, under
-    # packed dispatch, the occupancy-tier assignment
-    cg_all = jnp.concatenate(
-        [jnp.arange(G, dtype=jnp.int32)[:, None], nbr], axis=1)
-    total_all = jnp.sum(
-        jnp.where(cg_all >= 0, dg.counts[jnp.maximum(cg_all, 0)], 0),
-        axis=1)                                               # [G]
-    small_all = (~big) & occupied
-    ovf_candidates = jnp.any((total_all > caps.c_cap) & small_all)
+        # per-grid candidate totals (own + neighbor occupancies): the same
+        # numbers _candidates_for_grids derives per block, computed once for
+        # every grid -- they drive the candidates overflow flag and, under
+        # packed dispatch, the occupancy-tier assignment
+        cg_all = jnp.concatenate(
+            [jnp.arange(G, dtype=jnp.int32)[:, None], nbr], axis=1)
+        total_all = jnp.sum(
+            jnp.where(cg_all >= 0, dg.counts[jnp.maximum(cg_all, 0)], 0),
+            axis=1)                                               # [G]
+        small_all = (~big) & occupied
+        ovf_candidates = jnp.any((total_all > caps.c_cap) & small_all)
 
-    def core_rows(gsel, width, active):
-        """Core test of one grid block at candidate width ``width``:
-        identical values to the full-width pass for any grid whose
-        candidate total fits (no truncation, same candidate prefix
-        order, same distance rows)."""
-        cand_idx, _, cand_valid, _ = _candidates_for_grids(
-            dg, nbr, gsel, width)
-        cand_valid = cand_valid & sorted_valid[cand_idx]
-        own_slot = jnp.arange(p_cap, dtype=jnp.int32)[None, :]
-        own_idx = dg.starts[gsel][:, None] + own_slot
-        small = (~big[gsel]) & occupied[gsel] & active
-        own_valid = (own_slot < dg.counts[gsel][:, None]) & small[:, None]
-        own_idx = jnp.where(own_valid, own_idx, 0)
-        a = spts[own_idx]                       # [B, P, d]
-        b = spts[cand_idx]                      # [B, C, d]
-        if caps.use_kernels:
-            # stop_at=min_pts: the saturating-count contract -- exact
-            # below min_pts, ">= min_pts" above -- is all the core test
-            # needs, and it unlocks the paper's offset-ascending early
-            # exit (candidates are already in that order)
-            anchor = grid_anchor(gsel)
-            cnt = kernel_ops.eps_count_batch(a - anchor, b - anchor, eps,
-                                             valid_b=cand_valid,
-                                             valid_a=own_valid,
-                                             stop_at=min_pts)
+        def core_rows(gsel, width, active):
+            """Core test of one grid block at candidate width ``width``:
+            identical values to the full-width pass for any grid whose
+            candidate total fits (no truncation, same candidate prefix
+            order, same distance rows)."""
+            cand_idx, _, cand_valid, _ = _candidates_for_grids(
+                dg, nbr, gsel, width)
+            cand_valid = cand_valid & sorted_valid[cand_idx]
+            own_slot = jnp.arange(p_cap, dtype=jnp.int32)[None, :]
+            own_idx = dg.starts[gsel][:, None] + own_slot
+            small = (~big[gsel]) & occupied[gsel] & active
+            own_valid = (own_slot < dg.counts[gsel][:, None]) & small[:, None]
+            own_idx = jnp.where(own_valid, own_idx, 0)
+            a = spts[own_idx]                       # [B, P, d]
+            b = spts[cand_idx]                      # [B, C, d]
+            if caps.use_kernels:
+                # stop_at=min_pts: the saturating-count contract -- exact
+                # below min_pts, ">= min_pts" above -- is all the core test
+                # needs, and it unlocks the paper's offset-ascending early
+                # exit (candidates are already in that order)
+                anchor = grid_anchor(gsel)
+                cnt = kernel_ops.eps_count_batch(a - anchor, b - anchor, eps,
+                                                 valid_b=cand_valid,
+                                                 valid_a=own_valid,
+                                                 stop_at=min_pts)
+            else:
+                d2 = jnp.sum((a[:, :, None, :] - b[:, None, :, :]) ** 2,
+                             axis=-1)
+                hit = (d2 <= eps2) & cand_valid[:, None, :]
+                cnt = hit.sum(axis=2)
+            return own_idx, (cnt >= min_pts) & own_valid
+
+        GB = caps.grid_block
+        if caps.packed:
+            # occupancy-packed dispatch: live small grids compacted to a
+            # prefix sorted by candidate total (stable, so equal totals keep
+            # grid order), swept tier by tier at pow2 sub-caps.  A grid's
+            # tier width bounds its candidate total, so every tier sees the
+            # exact candidate set; grids whose total exceeds c_cap run (and
+            # truncate) in the widest tier exactly as the dense path does,
+            # with the candidates flag raised from total_all above.
+            tier_w = sorted({max(8, caps.c_cap // 4),
+                             max(8, caps.c_cap // 2), caps.c_cap})
+            pperm = jnp.argsort(jnp.where(small_all, total_all,
+                                          jnp.int32(2 ** 30)), stable=True)
+            n_small = jnp.sum(small_all.astype(jnp.int32))
+            cuts = [jnp.sum((small_all
+                             & (total_all <= w)).astype(jnp.int32))
+                    for w in tier_w[:-1]] + [n_small]
+            tier_bounds = list(zip([jnp.int32(0)] + cuts[:-1], cuts))
+            tier_counts = [hi - lo for lo, hi in tier_bounds]
+
+            def sweep_tiers(row_fn, init, scatter):
+                def one_tier(acc, lo, hi, width):
+                    nblk = (hi - lo + GB - 1) // GB
+
+                    def body(state):
+                        b, acc = state
+                        pos = lo + b * GB + jnp.arange(GB, dtype=jnp.int32)
+                        active = pos < hi
+                        gsel = pperm[jnp.where(active, pos, 0)]
+                        oi, val = row_fn(gsel, width, active)
+                        return b + 1, scatter(acc, oi, val)
+
+                    return jax.lax.while_loop(
+                        lambda s: s[0] < nblk, body, (jnp.int32(0), acc))[1]
+
+                for k, ((lo, hi), width) in enumerate(
+                        zip(tier_bounds, tier_w)):
+                    with jax.named_scope(f"tier{k + 1}"):
+                        init = one_tier(init, lo, hi, width)
+                return init
+
+            core_sorted = sweep_tiers(
+                core_rows, core_sorted,
+                lambda acc, oi, v: acc.at[oi.reshape(-1)].max(v.reshape(-1)))
+            dispatch_tiers = jnp.zeros((4,), jnp.int32)
+            for t, cnt in enumerate(tier_counts):
+                dispatch_tiers = dispatch_tiers.at[t].set(cnt)
         else:
-            d2 = jnp.sum((a[:, :, None, :] - b[:, None, :, :]) ** 2, axis=-1)
-            hit = (d2 <= eps2) & cand_valid[:, None, :]
-            cnt = hit.sum(axis=2)
-        return own_idx, (cnt >= min_pts) & own_valid
+            gsel_all = jnp.arange(G, dtype=jnp.int32).reshape(-1, GB)
+            ones = jnp.ones((GB,), bool)
+            own_idx, is_core = jax.lax.map(
+                lambda gsel: core_rows(gsel, caps.c_cap, ones), gsel_all)
+            core_sorted = core_sorted.at[own_idx.reshape(-1)].max(
+                is_core.reshape(-1))
+            dispatch_tiers = jnp.zeros((4,), jnp.int32).at[3].set(G)
 
-    GB = caps.grid_block
-    if caps.packed:
-        # occupancy-packed dispatch: live small grids compacted to a
-        # prefix sorted by candidate total (stable, so equal totals keep
-        # grid order), swept tier by tier at pow2 sub-caps.  A grid's
-        # tier width bounds its candidate total, so every tier sees the
-        # exact candidate set; grids whose total exceeds c_cap run (and
-        # truncate) in the widest tier exactly as the dense path does,
-        # with the candidates flag raised from total_all above.
-        tier_w = sorted({max(8, caps.c_cap // 4),
-                         max(8, caps.c_cap // 2), caps.c_cap})
-        pperm = jnp.argsort(jnp.where(small_all, total_all,
-                                      jnp.int32(2 ** 30)), stable=True)
-        n_small = jnp.sum(small_all.astype(jnp.int32))
-        cuts = [jnp.sum((small_all
-                         & (total_all <= w)).astype(jnp.int32))
-                for w in tier_w[:-1]] + [n_small]
-        tier_bounds = list(zip([jnp.int32(0)] + cuts[:-1], cuts))
-        tier_counts = [hi - lo for lo, hi in tier_bounds]
-
-        def sweep_tiers(row_fn, init, scatter):
-            def one_tier(acc, lo, hi, width):
-                nblk = (hi - lo + GB - 1) // GB
-
-                def body(state):
-                    b, acc = state
-                    pos = lo + b * GB + jnp.arange(GB, dtype=jnp.int32)
-                    active = pos < hi
-                    gsel = pperm[jnp.where(active, pos, 0)]
-                    oi, val = row_fn(gsel, width, active)
-                    return b + 1, scatter(acc, oi, val)
-
-                return jax.lax.while_loop(
-                    lambda s: s[0] < nblk, body, (jnp.int32(0), acc))[1]
-
-            for (lo, hi), width in zip(tier_bounds, tier_w):
-                init = one_tier(init, lo, hi, width)
-            return init
-
-        core_sorted = sweep_tiers(
-            core_rows, core_sorted,
-            lambda acc, oi, v: acc.at[oi.reshape(-1)].max(v.reshape(-1)))
-        dispatch_tiers = jnp.zeros((4,), jnp.int32)
-        for t, cnt in enumerate(tier_counts):
-            dispatch_tiers = dispatch_tiers.at[t].set(cnt)
-    else:
-        gsel_all = jnp.arange(G, dtype=jnp.int32).reshape(-1, GB)
-        ones = jnp.ones((GB,), bool)
-        own_idx, is_core = jax.lax.map(
-            lambda gsel: core_rows(gsel, caps.c_cap, ones), gsel_all)
-        core_sorted = core_sorted.at[own_idx.reshape(-1)].max(
-            is_core.reshape(-1))
-        dispatch_tiers = jnp.zeros((4,), jnp.int32).at[3].set(G)
-
-    core_per_grid = jnp.zeros((G,), jnp.int32).at[dg.point_grid].add(
-        core_sorted.astype(jnp.int32))
-    core_grid = (core_per_grid > 0) & live
-    ovf_core_set = jnp.any(core_per_grid > caps.m_cap)
+        core_per_grid = jnp.zeros((G,), jnp.int32).at[dg.point_grid].add(
+            core_sorted.astype(jnp.int32))
+        core_grid = (core_per_grid > 0) & live
+        ovf_core_set = jnp.any(core_per_grid > caps.m_cap)
 
     # ---- step 3: merging -----------------------------------------------
-    # pairs (g, g') with g' in Nei(g), both core, deduped by g' > g
-    K = caps.k_cap
-    gg = jnp.broadcast_to(jnp.arange(G, dtype=jnp.int32)[:, None], (G, K))
-    g2 = nbr
-    pair_valid = (g2 >= 0) & (g2 > gg) & core_grid[gg] & core_grid[
-        jnp.maximum(g2, 0)]
-    flat_valid = pair_valid.reshape(-1)
-    order = jnp.argsort(~flat_valid, stable=True)
-    take = order[:caps.pair_cap]
-    pg = gg.reshape(-1)[take]
-    ph = jnp.maximum(g2.reshape(-1), 0)[take]
-    pvalid = flat_valid[take]
-    if take.shape[0] < caps.pair_cap:
-        # pair_cap exceeds the G*K pair universe: pad the compacted
-        # prefix back up to the cap (all padding invalid) so the block
-        # reshape below keeps its static shape
-        pad = caps.pair_cap - take.shape[0]
-        pg = jnp.pad(pg, (0, pad))
-        ph = jnp.pad(ph, (0, pad))
-        pvalid = jnp.pad(pvalid, (0, pad))
-    ovf_pairs = jnp.sum(flat_valid) > caps.pair_cap
+    with jax.named_scope("grit.merge"):
+        # pairs (g, g') with g' in Nei(g), both core, deduped by g' > g
+        K = caps.k_cap
+        gg = jnp.broadcast_to(jnp.arange(G, dtype=jnp.int32)[:, None], (G, K))
+        g2 = nbr
+        pair_valid = (g2 >= 0) & (g2 > gg) & core_grid[gg] & core_grid[
+            jnp.maximum(g2, 0)]
+        flat_valid = pair_valid.reshape(-1)
+        order = jnp.argsort(~flat_valid, stable=True)
+        take = order[:caps.pair_cap]
+        pg = gg.reshape(-1)[take]
+        ph = jnp.maximum(g2.reshape(-1), 0)[take]
+        pvalid = flat_valid[take]
+        if take.shape[0] < caps.pair_cap:
+            # pair_cap exceeds the G*K pair universe: pad the compacted
+            # prefix back up to the cap (all padding invalid) so the block
+            # reshape below keeps its static shape
+            pad = caps.pair_cap - take.shape[0]
+            pg = jnp.pad(pg, (0, pad))
+            ph = jnp.pad(ph, (0, pad))
+            pvalid = jnp.pad(pvalid, (0, pad))
+        ovf_pairs = jnp.sum(flat_valid) > caps.pair_cap
 
-    # compacted core set of EVERY grid, computed once: each core grid
-    # takes part in ~k_cap merge pairs, so hoisting the compaction out
-    # of the pair blocks removes the dominant per-pair gather cost
-    def gather_core_set(g):
-        w = jnp.arange(caps.m_cap, dtype=jnp.int32)
-        pidx = dg.starts[g] + w
-        pidx = jnp.where(w < dg.counts[g], pidx, 0)
-        flag = core_sorted[pidx] & (w < dg.counts[g])
-        tgt = jnp.cumsum(flag.astype(jnp.int32)) - 1
-        out = jnp.zeros((caps.m_cap,), jnp.int32)
-        out = out.at[jnp.where(flag, tgt, caps.m_cap - 1)].max(
-            jnp.where(flag, pidx, 0))
-        m = flag.sum()
-        setv = jnp.arange(caps.m_cap) < m
-        return jnp.where(setv, out, 0), setv
+        # compacted core set of EVERY grid, computed once: each core grid
+        # takes part in ~k_cap merge pairs, so hoisting the compaction out
+        # of the pair blocks removes the dominant per-pair gather cost
+        def gather_core_set(g):
+            w = jnp.arange(caps.m_cap, dtype=jnp.int32)
+            pidx = dg.starts[g] + w
+            pidx = jnp.where(w < dg.counts[g], pidx, 0)
+            flag = core_sorted[pidx] & (w < dg.counts[g])
+            tgt = jnp.cumsum(flag.astype(jnp.int32)) - 1
+            out = jnp.zeros((caps.m_cap,), jnp.int32)
+            out = out.at[jnp.where(flag, tgt, caps.m_cap - 1)].max(
+                jnp.where(flag, pidx, 0))
+            m = flag.sum()
+            setv = jnp.arange(caps.m_cap) < m
+            return jnp.where(setv, out, 0), setv
 
-    core_set_idx, core_set_valid = jax.vmap(gather_core_set)(
-        jnp.arange(G, dtype=jnp.int32))                  # [G, m_cap]
+        core_set_idx, core_set_valid = jax.vmap(gather_core_set)(
+            jnp.arange(G, dtype=jnp.int32))                  # [G, m_cap]
 
-    def merge_block(args):
-        a_g, b_g, pv = args
-        av = core_set_valid[a_g] & pv[:, None]
-        bv = core_set_valid[b_g] & pv[:, None]
-        yes, iters = fast_merging_batch(
-            spts[core_set_idx[a_g]], av, spts[core_set_idx[b_g]], bv,
-            eps, max_iters=caps.merge_iters)
-        return yes & pv, iters
+        def merge_block(args):
+            a_g, b_g, pv = args
+            av = core_set_valid[a_g] & pv[:, None]
+            bv = core_set_valid[b_g] & pv[:, None]
+            yes, iters = fast_merging_batch(
+                spts[core_set_idx[a_g]], av, spts[core_set_idx[b_g]], bv,
+                eps, max_iters=caps.merge_iters)
+            return yes & pv, iters
 
-    PB = caps.pair_block
-    n_pb = caps.pair_cap // PB
-    if caps.packed:
-        # the valid pairs are argsort-compacted to a prefix above, so
-        # only ceil(n_valid / PB) blocks carry work; blocks past the
-        # prefix would compute all-False rows, which is exactly the
-        # initial value of ``merged`` -- skipping them is bit-identical
-        n_valid_pairs = jnp.minimum(
-            jnp.sum(flat_valid.astype(jnp.int32)), caps.pair_cap)
-        nblk_m = (n_valid_pairs + PB - 1) // PB
+        PB = caps.pair_block
+        n_pb = caps.pair_cap // PB
+        if caps.packed:
+            # the valid pairs are argsort-compacted to a prefix above, so
+            # only ceil(n_valid / PB) blocks carry work; blocks past the
+            # prefix would compute all-False rows, which is exactly the
+            # initial value of ``merged`` -- skipping them is bit-identical
+            n_valid_pairs = jnp.minimum(
+                jnp.sum(flat_valid.astype(jnp.int32)), caps.pair_cap)
+            nblk_m = (n_valid_pairs + PB - 1) // PB
 
-        def merge_body(state):
-            b, acc = state
-            s = b * PB
-            yes, _ = merge_block((
-                jax.lax.dynamic_slice(pg, (s,), (PB,)),
-                jax.lax.dynamic_slice(ph, (s,), (PB,)),
-                jax.lax.dynamic_slice(pvalid, (s,), (PB,))))
-            return b + 1, jax.lax.dynamic_update_slice(acc, yes, (s,))
+            def merge_body(state):
+                b, acc = state
+                s = b * PB
+                yes, _ = merge_block((
+                    jax.lax.dynamic_slice(pg, (s,), (PB,)),
+                    jax.lax.dynamic_slice(ph, (s,), (PB,)),
+                    jax.lax.dynamic_slice(pvalid, (s,), (PB,))))
+                return b + 1, jax.lax.dynamic_update_slice(acc, yes, (s,))
 
-        merged = jax.lax.while_loop(
-            lambda s: s[0] < nblk_m, merge_body,
-            (jnp.int32(0), jnp.zeros((caps.pair_cap,), bool)))[1]
-    else:
-        merged, _ = jax.lax.map(
-            merge_block, (pg.reshape(n_pb, PB), ph.reshape(n_pb, PB),
-                          pvalid.reshape(n_pb, PB)))
-        merged = merged.reshape(-1)
+            merged = jax.lax.while_loop(
+                lambda s: s[0] < nblk_m, merge_body,
+                (jnp.int32(0), jnp.zeros((caps.pair_cap,), bool)))[1]
+        else:
+            merged, _ = jax.lax.map(
+                merge_block, (pg.reshape(n_pb, PB), ph.reshape(n_pb, PB),
+                              pvalid.reshape(n_pb, PB)))
+            merged = merged.reshape(-1)
 
-    edges = jnp.stack([pg, ph], axis=1)
-    grid_label = label_propagation(G, edges, merged, core_grid)
-    # representative grid index per cluster; sentinel G for non-core grids
-    num_clusters = jnp.sum((grid_label == jnp.arange(G)) & core_grid)
+    with jax.named_scope("grit.components"):
+        edges = jnp.stack([pg, ph], axis=1)
+        grid_label = label_propagation(G, edges, merged, core_grid)
+        # representative grid index per cluster; sentinel G for non-core grids
+        num_clusters = jnp.sum((grid_label == jnp.arange(G)) & core_grid)
 
     # ---- step 4: border / noise ----------------------------------------
-    def border_rows(gsel, width, active):
-        cand_idx, cand_grid, cand_valid, _ = _candidates_for_grids(
-            dg, nbr, gsel, width)
-        cand_valid = cand_valid & core_sorted[cand_idx]
-        own_slot = jnp.arange(p_cap, dtype=jnp.int32)[None, :]
-        own_idx = dg.starts[gsel][:, None] + own_slot
-        small = (~big[gsel]) & occupied[gsel] & active
-        own_valid = (own_slot < dg.counts[gsel][:, None]) & small[:, None]
-        own_idx_s = jnp.where(own_valid, own_idx, 0)
-        noncore = own_valid & ~core_sorted[own_idx_s]
-        a = spts[own_idx_s]
-        b = spts[cand_idx]
-        if caps.use_kernels:
-            anchor = grid_anchor(gsel)
-            dbest, jbest = kernel_ops.row_min_batch(a - anchor, b - anchor,
-                                                    valid_b=cand_valid)
-            # jbest == -1: no core candidate at all (row_min contract);
-            # dbest is inf there, so the eps2 test already rejects it --
-            # the clamp only keeps the gather in range
-            gbest = jnp.take_along_axis(cand_grid,
-                                        jnp.maximum(jbest, 0), axis=1)
+    with jax.named_scope("grit.border"):
+        def border_rows(gsel, width, active):
+            cand_idx, cand_grid, cand_valid, _ = _candidates_for_grids(
+                dg, nbr, gsel, width)
+            cand_valid = cand_valid & core_sorted[cand_idx]
+            own_slot = jnp.arange(p_cap, dtype=jnp.int32)[None, :]
+            own_idx = dg.starts[gsel][:, None] + own_slot
+            small = (~big[gsel]) & occupied[gsel] & active
+            own_valid = (own_slot < dg.counts[gsel][:, None]) & small[:, None]
+            own_idx_s = jnp.where(own_valid, own_idx, 0)
+            noncore = own_valid & ~core_sorted[own_idx_s]
+            a = spts[own_idx_s]
+            b = spts[cand_idx]
+            if caps.use_kernels:
+                anchor = grid_anchor(gsel)
+                dbest, jbest = kernel_ops.row_min_batch(a - anchor, b - anchor,
+                                                        valid_b=cand_valid)
+                # jbest == -1: no core candidate at all (row_min contract);
+                # dbest is inf there, so the eps2 test already rejects it --
+                # the clamp only keeps the gather in range
+                gbest = jnp.take_along_axis(cand_grid,
+                                            jnp.maximum(jbest, 0), axis=1)
+            else:
+                d2 = jnp.sum((a[:, :, None, :] - b[:, None, :, :]) ** 2,
+                             axis=-1)
+                d2 = jnp.where(cand_valid[:, None, :], d2, jnp.inf)
+                jbest = jnp.argmin(d2, axis=2)
+                dbest = jnp.take_along_axis(d2, jbest[..., None],
+                                            axis=2)[..., 0]
+                gbest = jnp.take_along_axis(cand_grid, jbest, axis=1)
+            lab = jnp.where((dbest <= eps2) & noncore,
+                            grid_label[gbest], jnp.int32(G))
+            return own_idx_s, jnp.where(noncore, lab, G)
+
+        if caps.packed:
+            border_sorted = sweep_tiers(
+                border_rows, jnp.full((n,), jnp.int32(G)),
+                lambda acc, oi, v: acc.at[oi.reshape(-1)].min(v.reshape(-1)))
         else:
-            d2 = jnp.sum((a[:, :, None, :] - b[:, None, :, :]) ** 2, axis=-1)
-            d2 = jnp.where(cand_valid[:, None, :], d2, jnp.inf)
-            jbest = jnp.argmin(d2, axis=2)
-            dbest = jnp.take_along_axis(d2, jbest[..., None], axis=2)[..., 0]
-            gbest = jnp.take_along_axis(cand_grid, jbest, axis=1)
-        lab = jnp.where((dbest <= eps2) & noncore,
-                        grid_label[gbest], jnp.int32(G))
-        return own_idx_s, jnp.where(noncore, lab, G)
+            b_own_idx, b_lab = jax.lax.map(
+                lambda gsel: border_rows(gsel, caps.c_cap, ones), gsel_all)
+            border_sorted = jnp.full((n,), jnp.int32(G)).at[
+                b_own_idx.reshape(-1)].min(b_lab.reshape(-1))
 
-    if caps.packed:
-        border_sorted = sweep_tiers(
-            border_rows, jnp.full((n,), jnp.int32(G)),
-            lambda acc, oi, v: acc.at[oi.reshape(-1)].min(v.reshape(-1)))
-    else:
-        b_own_idx, b_lab = jax.lax.map(
-            lambda gsel: border_rows(gsel, caps.c_cap, ones), gsel_all)
-        border_sorted = jnp.full((n,), jnp.int32(G)).at[
-            b_own_idx.reshape(-1)].min(b_lab.reshape(-1))
+    with jax.named_scope("grit.output"):
+        lab_sorted = jnp.where(core_sorted, grid_label[dg.point_grid],
+                               border_sorted)
+        lab_sorted = jnp.where(lab_sorted >= G, -1, lab_sorted)
+        lab_sorted = jnp.where(sorted_valid, lab_sorted, -1)
 
-    lab_sorted = jnp.where(core_sorted, grid_label[dg.point_grid],
-                           border_sorted)
-    lab_sorted = jnp.where(lab_sorted >= G, -1, lab_sorted)
-    lab_sorted = jnp.where(sorted_valid, lab_sorted, -1)
-
-    labels = jnp.zeros((n,), jnp.int32).at[dg.order].set(lab_sorted)
-    core = jnp.zeros((n,), bool).at[dg.order].set(core_sorted)
-    point_grid = jnp.zeros((n,), jnp.int32).at[dg.order].set(dg.point_grid)
+        labels = jnp.zeros((n,), jnp.int32).at[dg.order].set(lab_sorted)
+        core = jnp.zeros((n,), bool).at[dg.order].set(core_sorted)
+        point_grid = jnp.zeros((n,), jnp.int32).at[dg.order].set(dg.point_grid)
     report = OverflowReport(
         grid=dg.overflow, frontier=ovf_frontier, neighbors=ovf_k,
         candidates=ovf_candidates, core_set=ovf_core_set, pairs=ovf_pairs,

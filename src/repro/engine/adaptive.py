@@ -27,6 +27,7 @@ exact stencil size), so the loop terminates even on adversarial data.
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import math
 from typing import List, Optional, Tuple
 
@@ -416,16 +417,22 @@ def adaptive_device_dbscan(points, eps: float, min_pts: int,
     pts = jnp.asarray(points, jnp.float32)
     n, d = pts.shape
     if caps is None:
-        caps = estimate_caps(np.asarray(points), eps, min_pts,
-                             point_valid=None if point_valid is None
-                             else np.asarray(point_valid),
-                             use_kernels=bool(use_kernels))
+        with obs.stage("engine.census"):
+            caps = estimate_caps(np.asarray(points), eps, min_pts,
+                                 point_valid=None if point_valid is None
+                                 else np.asarray(point_valid),
+                                 use_kernels=bool(use_kernels))
     elif use_kernels is not None and caps.use_kernels != use_kernels:
         caps = dataclasses.replace(caps, use_kernels=use_kernels)
+    tries = itertools.count()
 
     def run(c):
-        res = device_dbscan(pts, eps, min_pts, c, point_valid=point_valid)
-        return res, jax.device_get(res.report)
+        # one dispatch through the report fetch, which waits for the
+        # program: its device time is the trace's, so no histogram
+        with obs.span("engine.device.attempt", attempt=next(tries)):
+            res = device_dbscan(pts, eps, min_pts, c,
+                                point_valid=point_valid)
+            return res, jax.device_get(res.report)
 
     result, attempts = adaptive_loop(
         run,

@@ -41,6 +41,7 @@ from typing import Optional
 
 import numpy as np
 
+from repro import obs
 from repro.core.dbscan import brute_dbscan, grit_dbscan
 from repro.core.validate import core_flags
 
@@ -130,25 +131,28 @@ def _device_impl(points, eps, min_pts, name: str, *, caps=None,
     import jax.numpy as jnp
 
     t0 = time.perf_counter()
-    pts = np.asarray(points, np.float32)
-    n, d = pts.shape
-    _check_device_grid_range(pts, eps)
-    n_pad = _pad_bucket(n, pad_quantum)
-    padded = np.zeros((n_pad, d), np.float32)
-    padded[:n] = pts
-    valid = np.arange(n_pad) < n
+    with obs.stage("engine.device.prepare"):
+        pts = np.asarray(points, np.float32)
+        n, d = pts.shape
+        _check_device_grid_range(pts, eps)
+        n_pad = _pad_bucket(n, pad_quantum)
+        padded = np.zeros((n_pad, d), np.float32)
+        padded[:n] = pts
+        dev_pts = jnp.asarray(padded)
+        dev_valid = jnp.asarray(np.arange(n_pad) < n)
 
     res, attempts = adaptive_device_dbscan(
-        jnp.asarray(padded), eps, min_pts, caps,
-        point_valid=jnp.asarray(valid), max_retries=max_retries,
-        growth=growth, use_kernels=use_kernels)
-    labels = np.asarray(res.labels)[:n].astype(np.int64)
-    core = np.asarray(res.core)[:n]
-    return ClusterResult.build(
-        labels, name, core=core, attempts=attempts,
-        overflow=attempts[-1]["overflow"],
-        stats={"n": n, "n_padded": n_pad, "retries": len(attempts) - 1,
-               "t_total": time.perf_counter() - t0})
+        dev_pts, eps, min_pts, caps, point_valid=dev_valid,
+        max_retries=max_retries, growth=growth, use_kernels=use_kernels)
+    with obs.stage("engine.device.fetch"):
+        labels = np.asarray(res.labels)[:n].astype(np.int64)
+        core = np.asarray(res.core)[:n]
+        return ClusterResult.build(
+            labels, name, core=core, attempts=attempts,
+            overflow=attempts[-1]["overflow"],
+            stats={"n": n, "n_padded": n_pad,
+                   "retries": len(attempts) - 1,
+                   "t_total": time.perf_counter() - t0})
 
 
 @register_engine("device",
@@ -205,9 +209,11 @@ def _distributed_engine(points, eps, min_pts, *, mesh=None, caps=None,
         uk = (jax.default_backend() == "tpu") if use_kernels is None \
             else bool(use_kernels)
         n_shards = int(mesh.devices.size)
-        grit = estimate_shard_caps(pts, eps, min_pts, n_shards,
-                                   use_kernels=uk)
-        halo = min(census_halo_cap(pts, eps, n_shards), _pow2_at_least(n))
+        with obs.stage("engine.census"):
+            grit = estimate_shard_caps(pts, eps, min_pts, n_shards,
+                                       use_kernels=uk)
+            halo = min(census_halo_cap(pts, eps, n_shards),
+                       _pow2_at_least(n))
         caps = ClusterCaps(grit=grit, halo_cap=halo)
     elif use_kernels is not None and \
             caps.grit.use_kernels != bool(use_kernels):

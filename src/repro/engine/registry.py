@@ -189,7 +189,6 @@ def cluster(points, eps: float, min_pts: int, *,
             f"NaN/Inf); clean the input before clustering")
     name = resolve_auto() if engine == "auto" else engine
     spec = get_engine(name)
-    obs.counter(f"engine.cluster.{name}").inc()
     with obs.span("engine.cluster", engine=name, n=int(pts.shape[0]),
                   d=int(pts.shape[1])):
         result = spec.fn(pts, float(eps), int(min_pts), **opts)

@@ -9,9 +9,14 @@ and the overhead policy; the short version:
 
 * tracing **off** (default): ``obs.span(...)`` returns a shared no-op
   -- zero events, zero host syncs, the serving hot path is untouched;
+* profiler recording (``jax.profiler.start_trace``): every span also
+  writes a ``jax.profiler.TraceAnnotation`` of its name into the
+  profiler's trace -- and, with tracing off, nothing else (no sync);
 * tracing **on** (``REPRO_OBS=1`` or :func:`enable`): spans sync at
   close only, counters/histograms always record (they are host-side
-  integer adds and never sync).
+  integer adds and never sync);
+* :func:`stage` is a span plus an always-on ``<name>_s`` histogram of
+  its seconds, for the stages a benchmark reads without a trace.
 
 Environment switches (read once at import):
 
@@ -24,7 +29,9 @@ Environment switches (read once at import):
 from __future__ import annotations
 
 import atexit
+import contextlib
 import os
+import time
 from typing import Optional
 
 from . import export
@@ -42,8 +49,21 @@ __all__ = [
     "Counter", "Gauge", "Histogram",
     "install_jax_hooks", "jax_hooks_installed", "recompile_counts",
     "bench_meta", "git_rev", "export",
-    "note_flat_dispatch", "export_chrome",
+    "note_flat_dispatch", "export_chrome", "stage",
 ]
+
+
+@contextlib.contextmanager
+def stage(name: str, **attrs):
+    """One stage of a call: ``span(name, **attrs)`` around the block,
+    and the block's seconds observed into the default registry's
+    histogram ``<name>_s`` -- always, like every registry instrument
+    (two ``perf_counter`` reads and one ``observe``), so the two
+    cannot drift apart.  A block that raises is not observed."""
+    t0 = time.perf_counter()
+    with span(name, **attrs) as sp:
+        yield sp
+    histogram(f"{name}_s").observe(time.perf_counter() - t0)
 
 
 def note_flat_dispatch(stage: str, t_valid: int, bucket: int) -> None:

@@ -1,18 +1,29 @@
-"""Nestable span tracer with device-sync-aware timing.
+"""Nestable span tracer with device-sync-aware timing, bridged into
+the JAX profiler's trace.
 
 Overhead contract (DESIGN.md §9):
 
-* **Tracing off** (the default): :func:`span` returns one shared
-  module-level no-op object -- no event record, no attribute dict
-  walk, and crucially *no host sync*, so the serving hot path is
-  untouched and the ``hot-path-sync`` lint rule stays green by
-  construction.
+* **Tracing off and profiler idle** (the default): :func:`span`
+  returns one shared module-level no-op object -- one global read and
+  one ``TraceAnnotation.is_enabled()`` call, no event record, no
+  attribute dict walk, and crucially *no host sync*, so the serving
+  hot path is untouched and the ``hot-path-sync`` lint rule stays
+  green by construction.
+* **Profiler recording, tracing off**: a span opens a
+  ``jax.profiler.TraceAnnotation`` of the same name (its attributes
+  become the annotation's metadata) and nothing else -- no event
+  record and no sync even when ``sync=`` was given: the profiler sees
+  device time directly, and a traced run must keep the untraced run's
+  schedule.  The span lands on the host thread's line of the
+  ``.xplane.pb``, on the same clock as the device ops.
 * **Tracing on**: a span syncs *only at its close*, and only when the
   caller registered device values to block on (``Span.sync(...)`` or
   the ``sync=`` kwarg) -- one intended block point per stage, which is
   exactly the discipline the serving plane already follows.  Those
   close-time syncs are the only host syncs the tracer ever performs
-  and each carries a justified ``grit-lint`` pragma.
+  and each carries a justified ``grit-lint`` pragma.  When the
+  profiler records too, the span also opens its annotation, closed
+  after the sync so that it covers the device wait.
 
 Spans nest lexically (context managers); the tracer keeps a per-thread
 stack so the exporter can emit parent-ordered Chrome trace events and
@@ -26,6 +37,17 @@ from __future__ import annotations
 import threading
 import time
 from typing import Any, Dict, List, Optional
+
+try:
+    from jax.profiler import TraceAnnotation
+    # whether the JAX profiler is recording: jaxlib's static TraceMe
+    # check (no allocation, no lock)
+    _profiling = TraceAnnotation.is_enabled
+except ImportError:          # jax not importable: host spans still work
+    TraceAnnotation = None
+
+    def _profiling() -> bool:
+        return False
 
 __all__ = ["Tracer", "Span", "NOOP_SPAN", "span", "enabled", "enable",
            "disable", "get_tracer"]
@@ -55,13 +77,38 @@ class _NoopSpan:
 NOOP_SPAN = _NoopSpan()
 
 
+class _ProfilerSpan:
+    """The span while only the profiler records: a
+    ``TraceAnnotation`` of the span's name and nothing else -- no event
+    record and never a sync (``sync`` registers nothing)."""
+
+    __slots__ = ("_ann",)
+
+    def __init__(self, name: str, attrs: Dict[str, Any]):
+        self._ann = TraceAnnotation(name, **attrs)
+
+    def __enter__(self) -> "_ProfilerSpan":
+        self._ann.__enter__()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self._ann.__exit__(*exc)
+
+    def set(self, **attrs: Any) -> "_ProfilerSpan":
+        self._ann.set_metadata(**attrs)
+        return self
+
+    def sync(self, *values: Any) -> "_ProfilerSpan":
+        return self
+
+
 class Span:
     """One live span.  Use as a context manager; at ``__exit__`` it
     optionally blocks on the registered device values (so the recorded
     duration covers the device work the stage dispatched, not just the
     Python that enqueued it) and records one complete event."""
 
-    __slots__ = ("_tracer", "name", "attrs", "_sync", "_t0")
+    __slots__ = ("_tracer", "name", "attrs", "_sync", "_t0", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str,
                  attrs: Optional[Dict[str, Any]],
@@ -71,12 +118,15 @@ class Span:
         self.attrs = attrs
         self._sync = [sync] if sync is not None else []
         self._t0 = 0.0
+        self._ann = None
 
     def set(self, **attrs: Any) -> "Span":
         """Attach attributes mid-span (rendered as Chrome trace args)."""
         if self.attrs is None:
             self.attrs = {}
         self.attrs.update(attrs)
+        if self._ann is not None:
+            self._ann.set_metadata(**attrs)
         return self
 
     def sync(self, *values: Any) -> "Span":
@@ -85,6 +135,9 @@ class Span:
         return self
 
     def __enter__(self) -> "Span":
+        if _profiling():
+            self._ann = TraceAnnotation(self.name, **(self.attrs or {}))
+            self._ann.__enter__()
         self._tracer._push(self)
         self._t0 = time.perf_counter()
         return self
@@ -98,6 +151,8 @@ class Span:
             # tracing is off (span() returns NOOP_SPAN then)
             jax.block_until_ready(self._sync)  # grit-lint: disable=hot-path-sync -- enabled-mode span close is the stage's intended block point; tracing-off serving never reaches this line
         t1 = time.perf_counter()
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
         self._tracer._pop(self, self._t0, t1, error=exc_type is not None)
 
 
@@ -192,9 +247,12 @@ def disable() -> Optional[Tracer]:
 
 
 def span(name: str, sync: Optional[Any] = None, **attrs: Any):
-    """A span under the process tracer -- or the shared no-op when
-    tracing is off (the hot-path fast exit: one global read)."""
+    """A span under the process tracer, bridged into the profiler's
+    trace while it records -- or the shared no-op when neither is on
+    (the hot-path fast exit: one global read and one ``is_enabled()``)."""
     t = _TRACER
     if t is None:
-        return NOOP_SPAN
+        if not _profiling():
+            return NOOP_SPAN
+        return _ProfilerSpan(name, attrs)
     return Span(t, name, attrs or None, sync=sync)

@@ -44,10 +44,15 @@ def tracer():
 # disabled-tracer invariant
 # ---------------------------------------------------------------------------
 
-def test_disabled_tracer_is_shared_noop():
+def test_disabled_tracer_is_shared_noop(monkeypatch, tmp_path):
+    import jax
+    import jax.numpy as jnp
+    from jax.profiler import TraceAnnotation
+
     was = obs.enabled()
     obs.disable()
     try:
+        assert not TraceAnnotation.is_enabled()   # profiler idle too
         s1 = obs.span("anything", n=3)
         s2 = obs.span("else")
         assert s1 is s2 is obs.NOOP_SPAN
@@ -59,9 +64,35 @@ def test_disabled_tracer_is_shared_noop():
                 pass
         assert obs.get_tracer() is None
         assert not obs.enabled()
+
+        # profiler only: an annotation, and never a sync, even with
+        # device values registered; the same spy sees the tracer-on
+        # close sync, so it would see one here
+        x = jnp.ones(4)
+        jax.block_until_ready(x)
+        syncs = []
+        monkeypatch.setattr(jax, "block_until_ready",
+                            lambda *a, **k: syncs.append(a))
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            sp = obs.span("profiled", sync=x, n=3)
+            assert sp is not obs.NOOP_SPAN
+            with sp:
+                assert sp.sync(x) is sp and sp.set(k=1) is sp
+            assert syncs == [] and obs.get_tracer() is None
+            obs.enable(clear=True)
+            with obs.span("traced", sync=x):
+                pass
+            assert len(syncs) == 1
+            obs.disable()
+        finally:
+            jax.profiler.stop_trace()
+        assert obs.span("after") is obs.NOOP_SPAN
     finally:
         if was:
             obs.enable()
+        else:
+            obs.disable()
 
 
 def test_obs_package_clean_under_hot_path_sync_rule():
@@ -265,3 +296,102 @@ def test_serve_counters_survive_tracing_toggle(served_index):
     finally:
         if not was:
             obs.disable()
+
+
+# ---------------------------------------------------------------------------
+# the fit's stages: profiler-bridged spans, stage histograms, device scopes
+# ---------------------------------------------------------------------------
+
+STAGES = ("engine.device.prepare", "engine.census", "engine.device.attempt",
+          "engine.device.fetch")
+TIMED = ("engine.device.prepare", "engine.census", "engine.device.fetch")
+SCOPES = ("grit.grids", "grit.neighbors", "grit.core", "grit.core/tier1",
+          "grit.core/tier2", "grit.core/tier3", "grit.merge",
+          "grit.components", "grit.border", "grit.border/tier1",
+          "grit.border/tier2", "grit.border/tier3", "grit.output")
+
+
+@pytest.fixture(scope="module")
+def profiled_fit(tmp_path_factory):
+    """Two ``cluster(engine="device-kernels")`` calls on 3,000 points,
+    tracing off; the second under the JAX profiler.  Returns the result,
+    the host events of the profiler's ``.xplane.pb`` named after the
+    fit's spans, and the stage histograms' counts before and after."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+    from repro.engine import cluster
+
+    rng = np.random.default_rng(7)
+    centers = rng.uniform(0, 400, (6, 3))
+    pts = np.concatenate([c + rng.normal(0, 12, (500, 3)) for c in centers])
+    was = obs.enabled()
+    obs.disable()
+    reg = obs.registry()
+    before = {n: reg.histogram(f"{n}_s").count for n in TIMED}
+    try:
+        cluster(pts, 20.0, 10, engine="device-kernels")
+        tdir = tmp_path_factory.mktemp("xplane")
+        jax.profiler.start_trace(str(tdir))
+        try:
+            res = cluster(pts, 20.0, 10, engine="device-kernels")
+        finally:
+            jax.profiler.stop_trace()
+    finally:
+        if was:
+            obs.enable()
+    after = {n: reg.histogram(f"{n}_s").count for n in TIMED}
+    path = glob.glob(f"{tdir}/**/*.xplane.pb", recursive=True)[0]
+    events = [(plane.name, line.name, e.name, e.start_ns,
+               e.start_ns + e.duration_ns)
+              for plane in ProfileData.from_file(path).planes
+              for line in plane.lines for e in line.events
+              if e.name in STAGES + ("engine.cluster",)]
+    return res, events, before, after
+
+
+def test_fit_stage_spans_reach_the_profiler_trace(profiled_fit):
+    """``engine.cluster`` holds every stage span on the host thread's
+    line of the trace, one attempt span per adaptive attempt."""
+    res, events, _, _ = profiled_fit
+    roots = [e for e in events if e[2] == "engine.cluster"]
+    assert len(roots) == 1
+    plane, line, _, lo, hi = roots[0]
+    assert not plane.startswith("/device")
+    inside = [e for e in events if e[2] in STAGES]
+    assert {e[2] for e in inside} == set(STAGES)
+    for e in inside:
+        assert (e[0], e[1]) == (plane, line) and lo <= e[3] <= e[4] <= hi
+    assert sum(e[2] == "engine.device.attempt" for e in inside) == \
+        len(res.attempts)
+
+
+def test_fit_stage_histograms_and_coverage(profiled_fit):
+    """One observation per stage histogram per ``cluster()`` call, and
+    the stage spans cover at least 90 % of ``engine.cluster``."""
+    _, events, before, after = profiled_fit
+    assert all(after[n] - before[n] == 2 for n in TIMED), (before, after)
+    (lo, hi), = [(e[3], e[4]) for e in events if e[2] == "engine.cluster"]
+    staged = sum(e[4] - e[3] for e in events if e[2] in STAGES)
+    assert staged >= 0.9 * (hi - lo), (staged, hi - lo)
+
+
+def test_device_dbscan_phases_are_named_in_compiled_hlo(profiled_fit):
+    """Every ``grit.*`` phase scope reaches the compiled program's
+    ``op_name`` metadata (the packed tier sweeps nested under their
+    phase)."""
+    import re
+
+    import jax.numpy as jnp
+    from repro.core.device_dbscan import GritCaps, device_dbscan
+
+    res, _, _, _ = profiled_fit
+    caps = GritCaps(**res.attempts[-1]["caps"])
+    n = res.stats["n_padded"]
+    text = device_dbscan.lower(
+        jnp.zeros((n, 3), jnp.float32), 20.0, 10, caps,
+        point_valid=jnp.zeros((n,), bool)).compile().as_text()
+    found = re.findall(
+        r'op_name="jit\(device_dbscan\)/((grit\.[a-z]+)(?:/tier\d)?)', text)
+    assert {name for pair in found for name in pair} == set(SCOPES)

@@ -13,6 +13,7 @@ describes it holds the TPU library until it exits.
 """
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -66,7 +67,12 @@ def test_fit_kernel_compiles_at_paper_scale_widths(one_chip, name):
         args.append(_spec(thresh, jnp.float32, one_chip))
     compiled = jax.jit(functools.partial(fn, interpret=False)).lower(
         *args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    # the custom call is named after the op (the pallas_call's name=),
+    # whatever the Python kernel function is called
+    assert re.search(rf"%{name[:-len('_pallas')]}(\.\d+)? = .*custom-call\(",
+                     text)
 
 
 def test_flat_resident_distance_op_compiles_at_large_bucket(one_chip):
@@ -102,4 +108,10 @@ def test_device_fit_program_compiles_with_pallas_plane(one_chip,
     compiled = device_dbscan.lower(
         _spec((n, 3), jnp.float32, one_chip), 200.0, 9, caps,
         point_valid=_spec((n,), jnp.bool_, one_chip)).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    # the fit's two kernels by their stable names: the benchmark finds
+    # their device time by ``eps_count_batch|row_min_batch``
+    kernels = set(re.findall(r"%([A-Za-z0-9_]+?)(?:\.\d+)? = [^\n]*"
+                             r"custom-call\(", text))
+    assert kernels == {"eps_count_batch", "row_min_batch"}
