@@ -6,12 +6,14 @@ Before this driver, callers hand-tuned ``GritCaps`` per dataset and a
 missed cap silently truncated the result.  Now:
 
 1. :func:`estimate_caps` derives an initial ``GritCaps`` from *host-side
-   grid statistics* — an O(n log n) pass that is vanishing next to the
-   clustering itself: the non-empty-grid count bounds ``grid_cap``, the
-   max grid occupancy bounds ``m_cap`` (core points per grid can never
-   exceed occupancy), and :func:`stencil_census` walks the grid tree's
-   levels for every grid at once to count what ``frontier_cap``,
-   ``k_cap``, ``c_cap`` and ``pair_cap`` must hold.
+   grid statistics*, computed while the device waits before every fit
+   that estimates its caps: the non-empty-grid count bounds
+   ``grid_cap``, the max grid occupancy bounds ``m_cap`` (core points
+   per grid can never exceed occupancy), and :func:`stencil_census`
+   walks the grid tree's levels for every grid at once to count what
+   ``frontier_cap``, ``k_cap``, ``c_cap`` and ``pair_cap`` must hold.
+   Both read one set of int64 level keys (:class:`_GridLevels`), built
+   once per estimate.
 2. :func:`adaptive_device_dbscan` runs the jitted pipeline, reads the
    per-cap :class:`OverflowReport`, geometrically grows exactly the caps
    that overflowed, and retries.  Caps are quantized to powers of two /
@@ -39,7 +41,7 @@ from repro import obs
 from repro.core.device_dbscan import (GritCaps, DeviceDBSCANResult,
                                       OverflowReport, device_dbscan)
 from repro.core.grids import identifiers
-from repro.core.grid_tree import offset_stencil
+from repro.core.grid_tree import offset_stencil, radius
 
 
 class CapOverflowError(RuntimeError):
@@ -99,27 +101,6 @@ def stencil_neighbor_bound(d: int) -> int:
     return int(len(deltas)) - 1
 
 
-def grid_stats(points: np.ndarray, eps: float,
-               point_valid: Optional[np.ndarray] = None
-               ) -> Tuple[int, int]:
-    """(non-empty grid count, max occupancy) over the *valid* points."""
-    pts = np.asarray(points, np.float64)
-    if point_valid is not None:
-        pts = pts[np.asarray(point_valid, bool)]
-    if len(pts) == 0:
-        return 1, 1
-    ids, _, _ = identifiers(pts, eps)
-    _, counts = np.unique(ids, axis=0, return_counts=True)
-    return int(len(counts)), int(counts.max())
-
-
-def _lex_rows(a: np.ndarray) -> np.ndarray:
-    """Rows of an int array as a lexicographically sortable structured
-    view (for vectorized row membership via searchsorted)."""
-    a = np.ascontiguousarray(np.asarray(a, np.int64))
-    return a.view([("", a.dtype)] * a.shape[1]).ravel()
-
-
 @dataclasses.dataclass(frozen=True)
 class StencilCensus:
     """Exact host-side counts over the offset stencil of every non-empty
@@ -131,10 +112,121 @@ class StencilCensus:
     pairs: int        # unordered pairs of neighbouring non-empty grids
 
 
-def _lookup(keys: np.ndarray, probe: np.ndarray):
-    """(hit mask, position) of lex-row ``probe`` in sorted ``keys``."""
-    pos = np.minimum(np.searchsorted(keys, probe), len(keys) - 1)
-    return keys[pos] == probe, pos
+@dataclasses.dataclass(frozen=True)
+class _GridLevels:
+    """The non-empty grids' identifier prefixes, level by level, as
+    sorted 1-D int64 keys -- the grid tree's levels, built the way the
+    tree is.
+
+    Identifiers are shifted by the stencil radius ``r`` so every probe
+    ``c + delta`` stays non-negative.  Level ``j``'s key of a
+    ``(j+1)``-prefix is ``rank_{j-1}(its j-prefix) * radix[j] + c_j``
+    with ``radix[j] = max c_j + r + 1``, so a key, and a probe, is below
+    ``G_{j-1} * radix[j]``.  With ``G <= n`` and at most 2^22 cells per
+    axis (the device's identifier range) that fits int64 for every d,
+    where a flat mixed-radix key over all d axes overflows at d = 5 with
+    about 5,400 cells per axis.  The last level's keys are the grids
+    themselves."""
+
+    keys: Tuple[np.ndarray, ...]   # per level, sorted unique keys
+    radix: Tuple[int, ...]         # per level, the multiplier of the rank
+    counts: np.ndarray             # occupancy per grid (last-level order)
+    r: int                         # stencil radius the ids are shifted by
+
+
+def _grid_levels(points: np.ndarray, eps: float,
+                 point_valid: Optional[np.ndarray] = None
+                 ) -> Optional[_GridLevels]:
+    """Level keys of the valid points' grids; None when none is valid."""
+    pts = np.asarray(points, np.float64)
+    if point_valid is not None:
+        pts = pts[np.asarray(point_valid, bool)]
+    if len(pts) == 0:
+        return None
+    r = radius(pts.shape[1])
+    ids, _, _ = identifiers(pts, eps)     # non-negative per axis
+    rank = np.zeros(len(pts), np.int64)
+    keys, radix = [], []
+    for c in (ids + r).T:
+        m = int(c.max()) + r + 1
+        if len(keys) and len(keys[-1]) > np.iinfo(np.int64).max // m:
+            raise ValueError(
+                f"grid identifier span {m} per axis is too large for "
+                f"int64 level keys over {len(keys[-1])} grid prefixes")
+        key, rank = np.unique(rank * m + c, return_inverse=True)
+        keys.append(key)
+        radix.append(m)
+    return _GridLevels(keys=tuple(keys), radix=tuple(radix),
+                       counts=np.bincount(rank, minlength=len(keys[-1])),
+                       r=r)
+
+
+def grid_stats(points: np.ndarray, eps: float,
+               point_valid: Optional[np.ndarray] = None
+               ) -> Tuple[int, int]:
+    """(non-empty grid count, max occupancy) over the *valid* points."""
+    return _occupancy(_grid_levels(points, eps, point_valid))
+
+
+def _occupancy(levels: Optional[_GridLevels]) -> Tuple[int, int]:
+    if levels is None:
+        return 1, 1
+    return int(len(levels.counts)), int(levels.counts.max())
+
+
+def _census(levels: Optional[_GridLevels], min_pts: int) -> StencilCensus:
+    """Walk the stencil's prefix trie depth first over the level keys.
+
+    A trie node at depth ``j`` is a ``(j+1)``-vector ``delta`` with
+    partial offset ``sum(max(|delta_i| - 1, 0)^2) < d``; its children
+    extend it by one axis.  At each node every ``(j+1)``-prefix ``p`` of
+    the grids probes ``p + delta`` among the level's keys:
+    ``pos_{j-1}(p[:j] + delta[:j]) * radix[j] + c_j + delta_j``, taken
+    only where the parent probe hit.  Siblings' probes differ only in
+    ``delta_j``, so one int64 ``searchsorted`` serves all children of a
+    node.  A node none of whose probes hit has no hits below it, so its
+    subtree is skipped.  At most one ``(hit, pos)`` pair per level is
+    alive: O(d * G) memory however large the stencil (197,067 deltas at
+    d = 7)."""
+    if levels is None:
+        return StencilCensus(1, 1, 0, 0)
+    keys, radix, counts, r = (levels.keys, levels.radix, levels.counts,
+                              levels.r)
+    d = len(keys)
+    live = [np.zeros(len(k), np.int64) for k in keys]
+    totals = np.zeros(len(counts), np.int64)
+
+    def visit(j: int, off: int, hit: np.ndarray, pos: np.ndarray):
+        parent = keys[j] // radix[j]
+        base = pos[parent] * radix[j] + keys[j] % radix[j]
+        parent_hit = hit[parent]
+        # the children's delta_j run over -a..a; level keys are sorted
+        # and unique, so the first key >= base + delta_j moves on by
+        # one exactly where base + delta_j is a key
+        a = next(x for x in range(r, -1, -1)
+                 if off + max(x - 1, 0) ** 2 < d)
+        at = np.searchsorted(keys[j], base - a)
+        for dj in range(-a, a + 1):
+            p = np.minimum(at, len(keys[j]) - 1)
+            found = keys[j][p] == base + dj
+            at += found
+            h = parent_hit & found
+            if not h.any():
+                continue
+            live[j] += h
+            if j + 1 < d:
+                visit(j + 1, off + max(abs(dj) - 1, 0) ** 2, h, p)
+            else:
+                totals[h] += counts[p[h]]
+
+    visit(0, 0, np.ones(1, bool), np.zeros(1, np.int64))
+    # the last level's prefixes are the grids themselves (self included)
+    nbrs = live[-1] - 1
+    small = counts < min_pts
+    return StencilCensus(
+        candidates=int(totals[small].max()) if small.any() else 1,
+        frontier=max(1, max(int(lv.max()) for lv in live)),
+        neighbors=int(nbrs.max()), pairs=int(nbrs.sum()) // 2)
 
 
 def stencil_census(points: np.ndarray, eps: float, min_pts: int,
@@ -154,41 +246,11 @@ def stencil_census(points: np.ndarray, eps: float, min_pts: int,
     set, so it bounds every small grid's candidate total; all-core grids
     skip the candidate scan and don't constrain ``c_cap``.
 
-    Vectorized: one ``searchsorted`` over the lex-sorted prefixes per
-    stencil delta and level -- O(|stencil| * G log G), vanishing next to
-    the fit."""
-    pts = np.asarray(points, np.float64)
-    if point_valid is not None:
-        pts = pts[np.asarray(point_valid, bool)]
-    if len(pts) == 0:
-        return StencilCensus(1, 1, 0, 0)
-    d = pts.shape[1]
-    ids, _, _ = identifiers(pts, eps)
-    uids, counts = np.unique(np.asarray(ids, np.int64), axis=0,
-                             return_counts=True)
-    deltas = np.asarray(offset_stencil(d)[0], np.int64)
-    frontier = 1
-    for j in range(d):
-        pre = np.unique(uids[:, :j + 1], axis=0)        # lex-sorted
-        keys = _lex_rows(pre)
-        live = np.zeros(len(pre), np.int64)
-        for delta in np.unique(deltas[:, :j + 1], axis=0):
-            live += _lookup(keys, _lex_rows(pre + delta))[0]
-        frontier = max(frontier, int(live.max()))
-    # the last level's prefixes are the grids themselves (uids order)
-    nbrs = live - 1
-    small = counts < min_pts
-    cand = 1
-    if small.any():
-        keys = _lex_rows(uids)
-        totals = np.zeros(int(small.sum()), np.int64)
-        for delta in deltas:
-            hit, pos = _lookup(keys, _lex_rows(uids[small] + delta))
-            totals += np.where(hit, counts[pos], 0)
-        cand = int(totals.max())
-    return StencilCensus(candidates=cand, frontier=frontier,
-                         neighbors=int(nbrs.max()),
-                         pairs=int(nbrs.sum()) // 2)
+    Int64 lookups of the level keys, one per node of the stencil's
+    prefix trie (see :func:`_census`); the last level's lookups give
+    the candidate totals too.  It runs on the host while the device
+    waits, before every fit that estimates its caps."""
+    return _census(_grid_levels(points, eps, point_valid), min_pts)
 
 
 def _caps_from_stats(n: int, d: int, num_grids: int, max_occ: int,
@@ -246,9 +308,10 @@ def estimate_caps(points: np.ndarray, eps: float, min_pts: int,
     """
     pts = np.asarray(points)
     n, d = pts.shape
-    num_grids, max_occ = grid_stats(pts, eps, point_valid)
-    census = stencil_census(pts, eps, min_pts, point_valid)
-    return _caps_from_stats(n, d, num_grids, max_occ, census,
+    levels = _grid_levels(pts, eps, point_valid)
+    num_grids, max_occ = _occupancy(levels)
+    return _caps_from_stats(n, d, num_grids, max_occ,
+                            _census(levels, min_pts),
                             margin, extra_grids, use_kernels)
 
 
@@ -307,8 +370,8 @@ def estimate_shard_caps(points: np.ndarray, eps: float, min_pts: int,
     num_grids, max_occ, n_max = 1, 1, 1
     census = StencilCensus(1, 1, 0, 0)
     for sub in _shard_point_sets(pts, eps, n_shards):
-        g, o = grid_stats(sub, eps)
-        c = stencil_census(sub, eps, min_pts)
+        levels = _grid_levels(sub, eps)
+        (g, o), c = _occupancy(levels), _census(levels, min_pts)
         num_grids, max_occ = max(num_grids, g), max(max_occ, o)
         n_max = max(n_max, len(sub))
         census = StencilCensus(*(max(a, b) for a, b in zip(
