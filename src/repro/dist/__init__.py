@@ -28,15 +28,17 @@ See DESIGN.md §5 for the sharding strategy and exactness argument.
 
 from .sharding import (halo_bound, owner_of_slab, shard_points_by_slab,
                        slab_cuts)
-from .halo import boundary_census, census_halo_cap, halo_buffer
+from .halo import (boundary_bands, boundary_census, census_halo_cap,
+                   halo_buffer, halo_cap_of)
 from .rebalance import RebalancePolicy, Rebalancer
 from .step import ClusterCaps, cached_cluster_step, make_cluster_step
 from .api import DistributedFitResult, distributed_dbscan, distributed_fit
 
 __all__ = [
     "ClusterCaps", "DistributedFitResult", "RebalancePolicy", "Rebalancer",
-    "boundary_census", "cached_cluster_step", "census_halo_cap",
-    "distributed_dbscan", "distributed_fit", "halo_bound", "halo_buffer",
+    "boundary_bands", "boundary_census", "cached_cluster_step",
+    "census_halo_cap", "distributed_dbscan", "distributed_fit",
+    "halo_bound", "halo_buffer", "halo_cap_of",
     "make_cluster_step", "owner_of_slab", "shard_points_by_slab",
     "slab_cuts",
 ]

@@ -85,6 +85,11 @@ def distributed_fit(points: np.ndarray, eps: float, min_pts: int,
     attributes the fit's wall-clock per stage.  Staged and fused
     produce identical results; fused stays the untraced default
     because it saves two dispatch round-trips.
+
+    The host stages ``dist.fit.pack``, ``dist.fit.transfer`` and
+    ``dist.fit.unpack`` are ``obs.stage``s: each call observes their
+    seconds into the always-on histograms ``dist.fit.pack_s`` etc.,
+    traced or not.
     """
     if traced is None:
         traced = obs.enabled()
@@ -98,14 +103,14 @@ def distributed_fit(points: np.ndarray, eps: float, min_pts: int,
         # the grit caps per shard; see repro.engine.estimate_shard_caps)
         caps = ClusterCaps(halo_cap=census_halo_cap(pts, eps, n_shards))
     with obs.span("dist.fit", n=n, shards=n_shards, staged=traced):
-        with obs.span("dist.fit.pack"):
+        with obs.stage("dist.fit.pack"):
             order, cut_idx, cut_coords = slab_cuts(pts, eps, n_shards)
             pts_sh, valid_sh, perm = pack_slabs(pts, order, cut_idx,
                                                 pad_to=pad_to)
         cap = pts_sh.shape[1]
         if traced:
             _census_metrics(pts_sh, valid_sh, eps, caps, n_shards, cap)
-        with obs.span("dist.fit.transfer") as sp:
+        with obs.stage("dist.fit.transfer") as sp:
             flat_pts = jnp.asarray(pts_sh.reshape(n_shards * cap, -1))
             flat_valid = jnp.asarray(valid_sh.reshape(-1))
             sharding = NamedSharding(mesh, P(axes))
@@ -142,7 +147,7 @@ def distributed_fit(points: np.ndarray, eps: float, min_pts: int,
                 sp.sync(labels, core, point_grid)
             report = jax.device_get(report)
 
-        with obs.span("dist.fit.unpack"):
+        with obs.stage("dist.fit.unpack"):
             labels = unshard_by_perm(np.asarray(labels), perm,
                                      n).astype(np.int64)
             core = unshard_by_perm(np.asarray(core), perm, n, fill=False)

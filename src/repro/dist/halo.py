@@ -59,30 +59,42 @@ def halo_buffer(pts, valid, eps, side: str, cap: int):
     return buf.astype(jnp.float32), idx.astype(jnp.int32), overflow
 
 
-def boundary_census(points: np.ndarray, eps: float, n_shards: int) -> int:
-    """Worst per-side 2*eps boundary-band population of the slab
-    partition: the exact host-side mirror of :func:`halo_buffer`'s
-    selection predicate, maximized over every shard and both sides.
+def boundary_bands(points: np.ndarray, eps: float,
+                   n_shards: int) -> np.ndarray:
+    """Each shard's 2*eps boundary-band populations of the slab
+    partition, ``[n_shards, 2]`` (lo side, hi side): the exact
+    host-side mirror of :func:`halo_buffer`'s selection predicate on
+    every shard (an empty shard selects nothing).
 
-    ``slab_cuts`` is deterministic, so a ``halo_cap >= boundary_census``
-    can never overflow on the fit that sized it -- unlike the
-    ``halo_bound`` densest-window estimate, which bounds *any* window
-    and historically left halo buffers ~76% padding."""
+    ``slab_cuts`` is deterministic, so the worst side
+    (:func:`boundary_census`) sizes a halo cap that can never overflow
+    on the fit that sized it, and the sum is the rows that fit's halo
+    exchange selects over all sides (the ring ships the two outer
+    sides too; their receivers mask them off)."""
     from .sharding import slab_cuts
     pts = np.asarray(points, np.float64)
     order, cut_idx, _ = slab_cuts(pts, eps, n_shards)
     starts = np.concatenate([[0], cut_idx]).astype(np.int64)
     ends = np.concatenate([cut_idx, [len(pts)]]).astype(np.int64)
     x = pts[order, 0]
-    worst = 0
+    bands = np.zeros((n_shards, 2), np.int64)
     for s in range(n_shards):
         seg = x[starts[s]:ends[s]]
-        if not seg.size:
-            continue
-        worst = max(worst,
-                    int(np.sum(seg <= seg.min() + 2 * eps)),
-                    int(np.sum(seg >= seg.max() - 2 * eps)))
-    return worst
+        if seg.size:
+            bands[s] = (np.sum(seg <= seg.min() + 2 * eps),
+                        np.sum(seg >= seg.max() - 2 * eps))
+    return bands
+
+
+def boundary_census(points: np.ndarray, eps: float, n_shards: int) -> int:
+    """Worst per-side 2*eps boundary-band population of the slab
+    partition (:func:`boundary_bands`' largest entry).
+
+    A ``halo_cap >= boundary_census`` can never overflow on the fit
+    that sized it -- unlike the ``halo_bound`` densest-window estimate,
+    which bounds *any* window and historically left halo buffers ~76%
+    padding."""
+    return int(boundary_bands(points, eps, n_shards).max(initial=0))
 
 
 def _quarter_pow2_at_least(x: int, lo: int = 8) -> int:
@@ -104,8 +116,13 @@ def census_halo_cap(points: np.ndarray, eps: float, n_shards: int,
     """Halo cap sized from the actual boundary-band census (see
     :func:`boundary_census`), bucket-quantized so similarly-sized fits
     share one compiled SPMD step."""
-    return _quarter_pow2_at_least(boundary_census(points, eps, n_shards),
-                                  lo=lo)
+    return halo_cap_of(boundary_bands(points, eps, n_shards), lo=lo)
+
+
+def halo_cap_of(bands: np.ndarray, lo: int = 32) -> int:
+    """The quantized halo cap covering the worst side of
+    :func:`boundary_bands`' census."""
+    return _quarter_pow2_at_least(int(np.max(bands, initial=0)), lo=lo)
 
 
 def halo_census(pts_sh: np.ndarray, valid_sh: np.ndarray, eps: float,

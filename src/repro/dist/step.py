@@ -72,27 +72,30 @@ def make_cluster_step(mesh: Mesh, eps, min_pts: int, caps: ClusterCaps,
         # shard_map hands us the local block: [n_points_shard, d]
         me = jax.lax.axis_index(axes)
         # --- 1. halo exchange (both directions, ring) ---
-        lo_buf, lo_idx, ov1 = halo_buffer(pts, valid, eps, "lo", H)
-        hi_buf, hi_idx, ov2 = halo_buffer(pts, valid, eps, "hi", H)
-        right = [(i, (i + 1) % n_shards) for i in range(n_shards)]
-        left = [((i + 1) % n_shards, i) for i in range(n_shards)]
-        # my hi-edge points go to the right neighbor; lo-edge to the left
-        ghosts_from_left = jax.lax.ppermute(hi_buf, axes, right)
-        ghosts_from_right = jax.lax.ppermute(lo_buf, axes, left)
-        # ring wrap: shard 0 has no left neighbor in a slab decomposition
-        first = me == 0
-        last = me == n_shards - 1
-        ghosts_from_left = jnp.where(first, PAD_COORD, ghosts_from_left)
-        ghosts_from_right = jnp.where(last, PAD_COORD, ghosts_from_right)
+        with jax.named_scope("dist.halo"):
+            lo_buf, lo_idx, ov1 = halo_buffer(pts, valid, eps, "lo", H)
+            hi_buf, hi_idx, ov2 = halo_buffer(pts, valid, eps, "hi", H)
+            right = [(i, (i + 1) % n_shards) for i in range(n_shards)]
+            left = [((i + 1) % n_shards, i) for i in range(n_shards)]
+            # my hi-edge points go to the right neighbor; lo-edge to the left
+            ghosts_from_left = jax.lax.ppermute(hi_buf, axes, right)
+            ghosts_from_right = jax.lax.ppermute(lo_buf, axes, left)
+            # ring wrap: shard 0 has no left neighbor in a slab decomposition
+            first = me == 0
+            last = me == n_shards - 1
+            ghosts_from_left = jnp.where(first, PAD_COORD, ghosts_from_left)
+            ghosts_from_right = jnp.where(last, PAD_COORD, ghosts_from_right)
 
         # --- 2. local exact GriT-DBSCAN on own + ghosts ---
-        all_pts = jnp.concatenate([pts, ghosts_from_left, ghosts_from_right])
-        all_valid = jnp.concatenate([
-            valid,
-            jnp.any(ghosts_from_left < PAD_COORD / 2, axis=1),
-            jnp.any(ghosts_from_right < PAD_COORD / 2, axis=1)])
-        res = device_dbscan(all_pts.astype(jnp.float32), eps, min_pts,
-                            caps.grit, point_valid=all_valid)
+        with jax.named_scope("dist.local"):
+            all_pts = jnp.concatenate([pts, ghosts_from_left,
+                                       ghosts_from_right])
+            all_valid = jnp.concatenate([
+                valid,
+                jnp.any(ghosts_from_left < PAD_COORD / 2, axis=1),
+                jnp.any(ghosts_from_right < PAD_COORD / 2, axis=1)])
+            res = device_dbscan(all_pts.astype(jnp.float32), eps, min_pts,
+                                caps.grit, point_valid=all_valid)
         n_own = pts.shape[0]
         own_labels = res.labels[:n_own]
         own_core = res.core[:n_own]
@@ -103,28 +106,29 @@ def make_cluster_step(mesh: Mesh, eps, min_pts: int, caps: ClusterCaps,
         ghost_r_core = res.core[n_own + H:]
 
         # --- 3. reconcile: my labels of the ghosts go back to their home
-        back_to_left = jnp.where(ghost_l_core, ghost_l_labels, -1)
-        back_to_right = jnp.where(ghost_r_core, ghost_r_labels, -1)
-        # label the ghosts got at the neighbor, aligned with my halo idx
-        hi_remote = jax.lax.ppermute(back_to_left, axes, left)
-        lo_remote = jax.lax.ppermute(back_to_right, axes, right)
+        with jax.named_scope("dist.reconcile"):
+            back_to_left = jnp.where(ghost_l_core, ghost_l_labels, -1)
+            back_to_right = jnp.where(ghost_r_core, ghost_r_labels, -1)
+            # label the ghosts got at the neighbor, aligned with my halo idx
+            hi_remote = jax.lax.ppermute(back_to_left, axes, left)
+            lo_remote = jax.lax.ppermute(back_to_right, axes, right)
 
-        e_hi, ok_hi = shared_point_edges(
-            own_labels, own_core, hi_idx, hi_remote, me,
-            jnp.minimum(me + 1, n_shards - 1), L)
-        e_lo, ok_lo = shared_point_edges(
-            own_labels, own_core, lo_idx, lo_remote, me,
-            jnp.maximum(me - 1, 0), L)
-        ok_hi = ok_hi & ~last
-        ok_lo = ok_lo & ~first
-        edges = jnp.concatenate([e_hi, e_lo])              # [2H, 2]
-        edge_valid = jnp.concatenate([ok_hi, ok_lo])
+            e_hi, ok_hi = shared_point_edges(
+                own_labels, own_core, hi_idx, hi_remote, me,
+                jnp.minimum(me + 1, n_shards - 1), L)
+            e_lo, ok_lo = shared_point_edges(
+                own_labels, own_core, lo_idx, lo_remote, me,
+                jnp.maximum(me - 1, 0), L)
+            ok_hi = ok_hi & ~last
+            ok_lo = ok_lo & ~first
+            edges = jnp.concatenate([e_hi, e_lo])              # [2H, 2]
+            edge_valid = jnp.concatenate([ok_hi, ok_lo])
 
-        # --- 4. global components over (shard, label) space ---
-        gmap = global_component_map(edges, edge_valid, n_shards, L, axes)
-        glab = jnp.where(own_labels >= 0,
-                         gmap[me * L + jnp.maximum(own_labels, 0)],
-                         -1)
+            # --- 4. global components over (shard, label) space ---
+            gmap = global_component_map(edges, edge_valid, n_shards, L, axes)
+            glab = jnp.where(own_labels >= 0,
+                             gmap[me * L + jnp.maximum(own_labels, 0)],
+                             -1)
         # a fresh report: never mutate the pipeline result's own report
         report = dataclasses.replace(
             res.report, halo=res.report.halo | ov1 | ov2)

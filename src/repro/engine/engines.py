@@ -186,8 +186,11 @@ def _distributed_engine(points, eps, min_pts, *, mesh=None, caps=None,
     lines, so the worst shard's own + ghost-band point set bounds every
     shard-local table without inflating each shard to the global one;
     the halo cap comes from the boundary-band census
-    (``repro.dist.halo.census_halo_cap``) instead of the densest-window
-    upper bound that historically left halo buffers ~76% padding.
+    (``repro.dist.halo.boundary_bands``) instead of the densest-window
+    upper bound that historically left halo buffers ~76% padding, and
+    the same census's total feeds the always-on counter
+    ``dist.halo.rows`` (rows the halo exchange selects, per fit whose
+    caps the census sized).
 
     ``use_kernels`` selects the shard-local distance plane (it rides on
     ``ClusterCaps.grit`` -- the same static jit key as the caps): None
@@ -197,7 +200,8 @@ def _distributed_engine(points, eps, min_pts, *, mesh=None, caps=None,
     including over the plane carried by a caller-provided ``caps``.
     """
     import jax
-    from repro.dist import ClusterCaps, census_halo_cap, distributed_fit
+    from repro.dist import (ClusterCaps, boundary_bands, distributed_fit,
+                            halo_cap_of)
 
     t0 = time.perf_counter()
     pts = np.asarray(points, np.float64)
@@ -212,8 +216,10 @@ def _distributed_engine(points, eps, min_pts, *, mesh=None, caps=None,
         with obs.stage("engine.census"):
             grit = estimate_shard_caps(pts, eps, min_pts, n_shards,
                                        use_kernels=uk)
-            halo = min(census_halo_cap(pts, eps, n_shards),
-                       _pow2_at_least(n))
+            bands = boundary_bands(pts, eps, n_shards)
+            halo = min(halo_cap_of(bands), _pow2_at_least(n))
+        # the rows this fit's halo exchange selects, over all sides
+        obs.counter("dist.halo.rows").inc(int(bands.sum()))
         caps = ClusterCaps(grit=grit, halo_cap=halo)
     elif use_kernels is not None and \
             caps.grit.use_kernels != bool(use_kernels):
