@@ -212,9 +212,22 @@ def _candidates_for_grids(dg: DeviceGrids, nbr: jnp.ndarray, gsel: jnp.ndarray,
     """Candidate point indices for each grid in ``gsel``: own grid first,
     then neighbors in offset-ascending order (paper's early-exit order).
 
+    The K+1 stencil grids of a row are segments of its ``c_cap`` slots;
+    slot s lies in segment ``seg = min(#{k : cum[k] <= s}, K)`` (``cum``
+    the inclusive sizes, so ``searchsorted(cum, s, side="right")``), at
+    point ``s + starts[grid] - (cum - sizes)[seg]``.  Slots at or past
+    the row's total are invalid, with index 0 and the last segment's grid.
+
+    A slot's grid and point shift come from one pass over the K segment
+    boundaries, with no gather: ``x[seg] = x[0] + sum_k [cum[k] <= s]
+    (x[k+1] - x[k])``, reduced over a major axis so the compares fuse
+    into elementwise work and nothing of size [K, B, c_cap] is stored.
+    A per-slot binary search would cost ceil(log2(K+1)) rounds of
+    batched per-element gathers, which the TPU runs slowly: at d = 3
+    (K = 120) they took more device time than every distance.
+
     Returns (cand_idx [B, c_cap] into sorted points, cand_grid [B, c_cap],
     cand_valid [B, c_cap], cand_total [B])."""
-    B = gsel.shape[0]
     K = nbr.shape[1]
     cg = jnp.concatenate([gsel[:, None], nbr[gsel]], axis=1)        # [B, K+1]
     cg_valid = cg >= 0
@@ -223,16 +236,18 @@ def _candidates_for_grids(dg: DeviceGrids, nbr: jnp.ndarray, gsel: jnp.ndarray,
     cum = jnp.cumsum(sizes, axis=1)                                 # inclusive
     total = cum[:, -1]
     slots = jnp.arange(c_cap, dtype=jnp.int32)[None, :]             # [1, C]
-    # segment of each slot: first seg with cum > slot
-    seg = jax.vmap(lambda c, s: jnp.searchsorted(c, s, side="right"))(
-        cum, jnp.broadcast_to(slots, (B, c_cap)))
-    seg = jnp.minimum(seg, K)
-    prev = jnp.where(seg > 0,
-                     jnp.take_along_axis(cum, jnp.maximum(seg - 1, 0), axis=1),
-                     0)
-    within = slots - prev
-    g_of = jnp.take_along_axis(cgc, seg, axis=1)
-    idx = dg.starts[g_of] + within
+    # point index minus slot, per segment: the segment's first point less
+    # the slots before it
+    shift = dg.starts[cgc] - (cum - sizes)                          # [B, K+1]
+    past = cum[:, :K].T[:, :, None] <= slots[None]                  # [K, B, C]
+
+    def at_seg(x):
+        step = (x[:, 1:] - x[:, :-1]).T[:, :, None]                 # [K, B, 1]
+        return x[:, :1] + jnp.sum(jnp.where(past, step, 0), axis=0,
+                                  dtype=x.dtype)
+
+    g_of = at_seg(cgc)
+    idx = slots + at_seg(shift)
     valid = (slots < total[:, None])
     idx = jnp.where(valid, idx, 0)
     return idx, g_of, valid, total
