@@ -119,8 +119,7 @@ def main():
           f"bit-identical to host")
     st = idx.insert(queries[64:128])      # mutations keep buffers fresh
     print(f"  insert 64 more: donated-scatter flag updates + mirror "
-          f"re-ship, {st['t_total'] * 1e3:.1f}ms; benchmarks/run.py "
-          f"--serve-device gates device >= host throughput (BENCH_6)")
+          f"re-ship, {st['t_total'] * 1e3:.1f}ms")
     idx.drop_device_state()
 
     print("\ndistributed fit -> snapshot -> predict (the sharded plane):")

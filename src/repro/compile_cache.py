@@ -6,7 +6,7 @@ JAX's persistent compilation cache keeps those executables on disk, so a
 later process with the same program and caps loads them instead.
 
 :func:`enable` is called once by each entry point (``chip_smoke.py``,
-``benchmarks/run.py``, ``python -m repro.serve.driver``) -- never at
+``bench/main.py``, ``python -m repro.serve.driver``) -- never at
 import time and never by the tests:
 
 * ``JAX_COMPILATION_CACHE_DIR`` set: JAX reads it itself, and nothing

@@ -12,7 +12,7 @@ Pipeline (paper §4.4):
 Label contract: ``labels[i] >= 0`` cluster id, ``-1`` noise.  Cluster ids
 are arbitrary but consistent; use ``canonicalize_labels`` to compare.
 
-``brute_dbscan`` is the O(n^2) oracle used by tests and benchmarks.
+``brute_dbscan`` is the O(n^2) oracle the tests pin every engine to.
 """
 
 from __future__ import annotations

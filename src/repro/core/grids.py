@@ -11,7 +11,7 @@ CSR partition of the sorted order.
 Two implementations share the same semantics:
 
 * ``build_grids``        -- host path (numpy, dynamic shapes): used by the
-                            paper-faithful benchmarks and the LDF variant.
+                            paper-faithful host engines and the LDF variant.
 * ``build_grids_device`` -- device path (pure jnp, static ``grid_cap``):
                             jittable, feeds the distributed pipeline.
 """

@@ -23,7 +23,7 @@ Three engines, identical decisions:
                              center-distance filter, then brute force).
 
 All report the number of iterations (paper's kappa) and distance
-evaluations so the benchmarks can reproduce the paper's efficiency story.
+evaluations, the counts behind the paper's efficiency story.
 """
 
 from __future__ import annotations

@@ -1,5 +1,6 @@
-"""Shared scenario library: one catalogue of datasets for conformance
-tests AND benchmarks (so they stop duplicating data generation).
+"""Shared scenario library: one catalogue of datasets for the
+conformance tests, the examples and the chip smoke (so they stop
+duplicating data generation).
 
 Each :class:`Scenario` bundles a generator with the (eps, min_pts) that
 make it interesting, mirroring how Wang/Gu/Shun and de Berg et al.
@@ -554,7 +555,7 @@ def _plan_ttl_drift(rng: np.random.Generator, base: np.ndarray,
 
 def churn_scenarios() -> List[ChurnScenario]:
     """Interleaved insert/delete workloads for the mutation-plane
-    tests and ``benchmarks/run.py --churn``."""
+    tests."""
     base = scenario_map()
     return [
         ChurnScenario(name="churn-split-2d", base=base["blobs-2d"],

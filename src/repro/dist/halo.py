@@ -100,8 +100,9 @@ def boundary_census(points: np.ndarray, eps: float, n_shards: int) -> int:
 def _quarter_pow2_at_least(x: int, lo: int = 8) -> int:
     """Smallest value >= x on the quarter-pow2 ladder (1, 1.25, 1.5,
     1.75 x 2^e): few distinct shapes like a plain pow2 bucket, but the
-    over-provisioning is bounded at 25% instead of 100% -- what keeps
-    the halo padding-waste gate (<= 25%, BENCH_8) honest."""
+    over-provisioning is bounded at 25% instead of 100%, so the shared
+    SPMD halo buffer is never more than a quarter slack over the worst
+    boundary side."""
     x = max(int(x), lo, 8)
     e = max((x - 1).bit_length() - 1, 3)
     for m in (5, 6, 7, 8):
@@ -132,13 +133,10 @@ def halo_census(pts_sh: np.ndarray, valid_sh: np.ndarray, eps: float,
 
     Returns ``(points_selected, buffer_slots, worst_side)`` where
     ``buffer_slots = 2 * n_shards * cap`` and ``worst_side`` is the
-    largest single side's selection.  The cap-sizing padding waste is
-    ``1 - worst_side / cap``: SPMD needs one shared buffer shape, so
-    the cap must cover the worst side and the slack on lighter sides
-    is irreducible -- only the worst-side slack is the cap estimator's
-    to close (the ``dist.halo.padding_waste`` gauge, gated <= 25% by
-    BENCH_8 via the quarter-pow2 cap ladder).  Pure numpy on the
-    pre-packed slabs; never dispatches to the device.
+    largest single side's selection.  It re-derives, independently of
+    :func:`boundary_bands`, the rows the exchange ships -- the count
+    the census behind ``dist.halo.rows`` must equal.  Pure numpy on
+    the pre-packed slabs; never dispatches to the device.
     """
     pts_sh = np.asarray(pts_sh)
     valid_sh = np.asarray(valid_sh, bool)

@@ -19,11 +19,11 @@ topology op per ``period`` steps:
 
 Amortization is the point: a split is O(n_shard) once, the imbalance
 it removes is O(n_hot) *every step*.  The period bounds topology churn
-so the reconcile cost never competes with serving (BENCH_9 measures
-the net win).  Splits that cannot make progress (single grid column,
-< 2 own points) raise ``ValueError`` inside the index; the policy
-marks that shard unsplittable until the topology changes again and
-falls through to the merge arm.
+so the reconcile cost never competes with serving.  Splits that cannot
+make progress (single grid column, < 2 own points) raise
+``ValueError`` inside the index; the policy marks that shard
+unsplittable until the topology changes again and falls through to the
+merge arm.
 """
 
 from __future__ import annotations

@@ -18,6 +18,10 @@ Each shard sends its boundary buffers to the adjacent shard with
 shard 0 has no left neighbor) and the ghosts' locally assigned labels
 travel back over the same permutation, reversed.
 
+This is the one SPMD program of a distributed fit, traced or not: the
+``dist.halo`` / ``dist.local`` / ``dist.reconcile`` named scopes let a
+profiler trace attribute its device time per stage.
+
 Compiled steps are cached by everything that shapes the program; the
 cache evicts its *oldest* entry at capacity (insertion order, refreshed
 on hit) so an adaptive-cap retry loop -- which alternates between at
@@ -148,139 +152,23 @@ def make_cluster_step(mesh: Mesh, eps, min_pts: int, caps: ClusterCaps,
     return cluster_step
 
 
-def make_staged_cluster_steps(mesh: Mesh, eps, min_pts: int,
-                              caps: ClusterCaps, n_points_shard: int,
-                              d: int):
-    """The SPMD step as three separately-jitted stage programs.
-
-    Same math as :func:`make_cluster_step`, but the fused program is
-    split at its stage boundaries -- (1) halo exchange, (2) local
-    cluster, (3) reconcile -- so a *traced* distributed fit
-    (``repro.obs``) can block between dispatches and attribute
-    wall-clock to each stage (ROADMAP item 2: is the 20x gap
-    recompilation, halo over-exchange, or cap over-padding?).  The
-    stage outputs are exactly the fused step's intermediates, so
-    staged and fused fits produce identical labels / core flags /
-    grids (pinned by ``tests/test_obs.py``); the split costs two extra
-    dispatch round-trips plus the materialized intermediates, which is
-    why the fused step remains the untraced default.
-
-    Returns ``(halo_fn, local_fn, reconcile_fn)``, all jitted:
-
-    * ``halo_fn(points, valid) -> (ghosts_l, ghosts_r, lo_idx, hi_idx,
-      halo_overflow)``
-    * ``local_fn(points, valid, ghosts_l, ghosts_r) -> (labels, core,
-      point_grid, gl_labels, gl_core, gr_labels, gr_core, report_vec)``
-    * ``reconcile_fn(labels, core, gl_labels, gl_core, gr_labels,
-      gr_core, lo_idx, hi_idx) -> global labels``
-    """
-    axes = tuple(mesh.axis_names)
-    n_shards = int(np.prod([mesh.shape[a] for a in axes]))
-    L = caps.grit.grid_cap
-    H = caps.halo_cap
-    right = [(i, (i + 1) % n_shards) for i in range(n_shards)]
-    left = [((i + 1) % n_shards, i) for i in range(n_shards)]
-
-    def halo_step(pts, valid):
-        me = jax.lax.axis_index(axes)
-        lo_buf, lo_idx, ov1 = halo_buffer(pts, valid, eps, "lo", H)
-        hi_buf, hi_idx, ov2 = halo_buffer(pts, valid, eps, "hi", H)
-        ghosts_from_left = jax.lax.ppermute(hi_buf, axes, right)
-        ghosts_from_right = jax.lax.ppermute(lo_buf, axes, left)
-        ghosts_from_left = jnp.where(me == 0, PAD_COORD,
-                                     ghosts_from_left)
-        ghosts_from_right = jnp.where(me == n_shards - 1, PAD_COORD,
-                                      ghosts_from_right)
-        return (ghosts_from_left, ghosts_from_right, lo_idx, hi_idx,
-                (ov1 | ov2)[None])
-
-    def local_step(pts, valid, ghosts_l, ghosts_r):
-        all_pts = jnp.concatenate([pts, ghosts_l, ghosts_r])
-        all_valid = jnp.concatenate([
-            valid,
-            jnp.any(ghosts_l < PAD_COORD / 2, axis=1),
-            jnp.any(ghosts_r < PAD_COORD / 2, axis=1)])
-        res = device_dbscan(all_pts.astype(jnp.float32), eps, min_pts,
-                            caps.grit, point_valid=all_valid)
-        n_own = pts.shape[0]
-        return (res.labels[:n_own], res.core[:n_own],
-                res.point_grid[:n_own],
-                res.labels[n_own:n_own + H], res.core[n_own:n_own + H],
-                res.labels[n_own + H:], res.core[n_own + H:],
-                res.report.as_vector()[None, :])
-
-    def reconcile_step(own_labels, own_core, gl_lab, gl_core,
-                       gr_lab, gr_core, lo_idx, hi_idx):
-        me = jax.lax.axis_index(axes)
-        first = me == 0
-        last = me == n_shards - 1
-        back_to_left = jnp.where(gl_core, gl_lab, -1)
-        back_to_right = jnp.where(gr_core, gr_lab, -1)
-        hi_remote = jax.lax.ppermute(back_to_left, axes, left)
-        lo_remote = jax.lax.ppermute(back_to_right, axes, right)
-        e_hi, ok_hi = shared_point_edges(
-            own_labels, own_core, hi_idx, hi_remote, me,
-            jnp.minimum(me + 1, n_shards - 1), L)
-        e_lo, ok_lo = shared_point_edges(
-            own_labels, own_core, lo_idx, lo_remote, me,
-            jnp.maximum(me - 1, 0), L)
-        ok_hi = ok_hi & ~last
-        ok_lo = ok_lo & ~first
-        edges = jnp.concatenate([e_hi, e_lo])
-        edge_valid = jnp.concatenate([ok_hi, ok_lo])
-        gmap = global_component_map(edges, edge_valid, n_shards, L, axes)
-        return jnp.where(own_labels >= 0,
-                         gmap[me * L + jnp.maximum(own_labels, 0)],
-                         -1)
-
-    s1 = P(axes)
-    s2 = P(axes, None)
-    halo = jax.shard_map(halo_step, mesh=mesh, in_specs=(s2, s1),
-                         out_specs=(s2, s2, s1, s1, s1), check_vma=False)
-    local = jax.shard_map(local_step, mesh=mesh,
-                          in_specs=(s2, s1, s2, s2),
-                          out_specs=(s1, s1, s1, s1, s1, s1, s1, s2),
-                          check_vma=False)
-    reconcile = jax.shard_map(reconcile_step, mesh=mesh,
-                              in_specs=(s1,) * 8, out_specs=s1,
-                              check_vma=False)
-    return jax.jit(halo), jax.jit(local), jax.jit(reconcile)
-
-
 # jitted SPMD steps keyed by everything that shapes the program; reused
 # across distributed fits so the adaptive driver's quantized cap
 # retries (and repeated runs on similarly-sized data) don't recompile.
-# Fused and staged (traced) programs share the cache, disambiguated by
-# the key's trailing flavor tag.
 _STEP_CACHE: dict = {}
 _STEP_CACHE_MAX = 32
 
 
-def _step_cache_get(key, build):
+def cached_cluster_step(mesh: Mesh, eps: float, min_pts: int,
+                        caps: ClusterCaps, n_points_shard: int, d: int):
+    key = (mesh, float(eps), int(min_pts), caps, int(n_points_shard),
+           int(d))
     if key in _STEP_CACHE:
         # refresh insertion order: a hit is the newest entry again
         _STEP_CACHE[key] = _STEP_CACHE.pop(key)
     else:
         while len(_STEP_CACHE) >= _STEP_CACHE_MAX:
             _STEP_CACHE.pop(next(iter(_STEP_CACHE)))
-        _STEP_CACHE[key] = build()
-    return _STEP_CACHE[key]
-
-
-def cached_cluster_step(mesh: Mesh, eps: float, min_pts: int,
-                        caps: ClusterCaps, n_points_shard: int, d: int):
-    key = (mesh, float(eps), int(min_pts), caps, int(n_points_shard),
-           int(d), "fused")
-    return _step_cache_get(
-        key, lambda: jax.jit(make_cluster_step(
-            mesh, eps, min_pts, caps, n_points_shard, d)))
-
-
-def cached_staged_cluster_steps(mesh: Mesh, eps: float, min_pts: int,
-                                caps: ClusterCaps, n_points_shard: int,
-                                d: int):
-    key = (mesh, float(eps), int(min_pts), caps, int(n_points_shard),
-           int(d), "staged")
-    return _step_cache_get(
-        key, lambda: make_staged_cluster_steps(
+        _STEP_CACHE[key] = jax.jit(make_cluster_step(
             mesh, eps, min_pts, caps, n_points_shard, d))
+    return _STEP_CACHE[key]

@@ -138,8 +138,8 @@ def _row_cross(a_vals: np.ndarray, a_sizes: np.ndarray,
 # twin instead of dispatching: upload + dispatch + sync overhead on a
 # tiny batch exceeds the f32 math win, and the host twin IS the
 # reference the device path is pinned against, so the shortcut cannot
-# change any output.  Tuned on the CPU backend (BENCH_6 workload);
-# large mutations and every predict stay on the kernel path.
+# change any output.  Tuned on a 1-core CPU, not on a chip; large
+# mutations and every predict stay on the kernel path.
 MIN_FLAT_T = 1 << 15
 # the edge decider's host twin early-terminates per pair (most
 # re-decided edges are confirmed by the first probe), while the flat

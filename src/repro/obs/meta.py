@@ -1,11 +1,11 @@
-"""Run provenance: one dict stamped into every benchmark artifact.
+"""Run provenance: one dict stamped into every exported trace.
 
-``BENCH_*.json`` files are the repo's perf trajectory, but a number
-without its machine is unauditable -- a 1.07x device win on a 1-core
-CPU runner and the same ratio on a TPU runner are different facts.
-:func:`bench_meta` captures the invariants that make a benchmark row
-comparable: jax/jaxlib versions, backend + device kind/count, host
-platform, an ISO-8601 UTC timestamp, and the git revision.
+A number without its machine is unauditable -- a 1.07x device win on a
+1-core CPU runner and the same ratio on a TPU runner are different
+facts.  :func:`bench_meta` captures the invariants that make a
+measurement comparable: jax/jaxlib versions, backend + device
+kind/count, host platform, an ISO-8601 UTC timestamp, and the git
+revision.
 """
 
 from __future__ import annotations
@@ -37,7 +37,7 @@ def git_rev() -> str:
 
 
 def bench_meta() -> Dict[str, Any]:
-    """Provenance block for benchmark emitters (JSON-able)."""
+    """Provenance block for exported traces (JSON-able)."""
     meta: Dict[str, Any] = {
         "timestamp": datetime.now(timezone.utc).isoformat(
             timespec="seconds"),

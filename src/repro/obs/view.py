@@ -1,13 +1,12 @@
 """Text summarizer for exported traces: ``python -m repro.obs.view
 <trace.json> [--root NAME]``.
 
-Prints the attribution table ROADMAP item 2 asks for: per-span-name
-totals (count / total / mean / self time), each name's share of the
-chosen root span's wall-clock, the coverage of the root by its direct
-children (how much of the wall is *attributed* rather than guessed),
-the top jit-compile counters, and the padding-waste /
-bucket-occupancy metrics.  Reads both export formats (Chrome trace
-JSON and JSONL).
+Prints an attribution table: per-span-name totals (count / total /
+mean / self time), each name's share of the chosen root span's
+wall-clock, the coverage of the root by its direct children (how much
+of the wall is *attributed* rather than guessed), the top jit-compile
+counters, and the bucket-occupancy metrics.  Reads both export formats
+(Chrome trace JSON and JSONL).
 """
 
 from __future__ import annotations
@@ -136,8 +135,7 @@ def render(events: List[Dict[str, Any]], metrics: Dict[str, Any],
                            key=lambda kv: -kv[1])[:8]:
             lines.append(f"  {k:<44}{v:>8}")
     waste = {k: v for k, v in metrics.items()
-             if "padding_waste" in k or "bucket_elems" in k
-             or k.endswith(".elems")}
+             if "bucket_elems" in k or k.endswith(".elems")}
     if waste:
         lines.append("")
         lines.append("padding / occupancy:")

@@ -449,8 +449,8 @@ _SHARED_DELETE = {"op", "requested", "deleted", "rejected",
 
 def test_unified_mutation_stats_schema():
     """GritIndex and ShardedGritIndex mutations share one stats schema
-    (sharded sums the counters), so the serve driver and benchmarks
-    can consume either without special-casing."""
+    (sharded sums the counters), so the serve driver can consume
+    either without special-casing."""
     rng = np.random.default_rng(31)
     base = rng.uniform(0, 100, size=(160, 2))
     eps, min_pts = 6.0, 4

@@ -197,42 +197,3 @@ def test_shardmap_moe_matches_reference():
     """)
     assert out.count("OK") == 4
 
-
-def test_traced_fit_staged_matches_fused():
-    """The staged SPMD step (halo / local / reconcile as separate
-    programs, used by the tracer for stage-boundary timing) must be
-    bit-identical to the fused default, and the traced fit's stage
-    spans must account for >= 90% of the dist.fit wall-clock."""
-    out = _run("""
-        import numpy as np, jax
-        from repro import obs
-        from repro.obs import view as obs_view
-        from repro.data.scenarios import get_scenario
-        from repro.engine import cluster
-        from repro.dist.api import distributed_fit
-
-        mesh = jax.make_mesh((4,), ("shard",))
-        sc = get_scenario("blobs-2d")
-        n = 4000
-        eps = sc.eps * (sc.n / n) ** (1.0 / sc.d)
-        pts = sc.points(n=n)
-
-        fused = distributed_fit(pts, eps, sc.min_pts, mesh, traced=False)
-        obs.enable(clear=True)
-        staged = distributed_fit(pts, eps, sc.min_pts, mesh, traced=True)
-        events = obs.get_tracer().snapshot_events()
-        obs.disable()
-
-        assert np.array_equal(fused.labels, staged.labels)
-        assert np.array_equal(fused.core, staged.core)
-        assert bool(fused.report) == bool(staged.report)
-        print("PARITY OK")
-
-        att = obs_view.attribution(events, root="dist.fit")
-        stages = {k.rsplit(".", 1)[-1] for k in att["children"]}
-        assert {"pack", "halo_exchange", "local_cluster",
-                "reconcile"} <= stages, stages
-        assert att["coverage"] >= 0.9, att["coverage"]
-        print(f"COVERAGE OK {att['coverage']:.3f}")
-    """)
-    assert "PARITY OK" in out and "COVERAGE OK" in out

@@ -1,11 +1,10 @@
-"""Small pure-python units: caps sizing, registry, shapes, report."""
+"""Small pure-python units: caps sizing, registry, shapes."""
 
 import math
 
 from repro.core.device_dbscan import GritCaps
 from repro.configs import canonical, list_archs, get_shape, SHAPES
 from repro.configs.registry import long_500k_supported
-from benchmarks.roofline_report import build_table
 
 
 def test_gritcaps_for_dim_fanout_bound():
@@ -37,17 +36,3 @@ def test_long_500k_policy():
     assert long_500k_supported("mixtral-8x7b")     # bounded SWA window
     assert not long_500k_supported("gemma2-27b")   # global layers
 
-
-def test_roofline_report_table():
-    recs = [
-        {"arch": "a", "shape": "train_4k", "mesh": "16x16", "status": "ok",
-         "kind": "train", "chips": 256, "flops_per_chip": 1e12,
-         "bytes_per_chip": 1e12,
-         "roofline": {"t_compute": 1e-2, "t_memory": 2e-2,
-                      "t_collective": 1e-3, "dominant": "memory",
-                      "bound": 2e-2, "compute_fraction": 0.5}},
-        {"arch": "b", "shape": "long_500k", "mesh": "16x16",
-         "status": "skipped", "reason": "full-attention arch"},
-    ]
-    t = build_table(recs)
-    assert "memory" in t and "skip" in t

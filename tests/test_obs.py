@@ -16,7 +16,7 @@ Pins the four invariants the plane is built on:
   resolve of batch k) must account every request/query exactly once,
   and ``summary()`` stays a faithful view over the registry.
 * **Provenance stamps are complete** -- ``bench_meta()`` carries the
-  fields that make a BENCH row comparable across machines.
+  fields that make a measurement comparable across machines.
 """
 
 import json
@@ -395,3 +395,37 @@ def test_device_dbscan_phases_are_named_in_compiled_hlo(profiled_fit):
     found = re.findall(
         r'op_name="jit\(device_dbscan\)/((grit\.[a-z]+)(?:/tier\d)?)', text)
     assert {name for pair in found for name in pair} == set(SCOPES)
+
+
+def test_tracing_does_not_change_the_distributed_program(monkeypatch):
+    """A traced distributed fit runs the untraced fit's one SPMD step:
+    same answers, one compiled program, and only the fused step's span
+    on the host -- a trace times the program users run."""
+    import jax
+    from jax.sharding import Mesh
+
+    from repro.dist import step as dist_step
+    from repro.engine import cluster
+    from repro.obs import trace as obs_trace
+
+    rng = np.random.default_rng(17)
+    pts = np.concatenate([rng.normal(c, 1.5, (400, 2))
+                          for c in ((0, 0), (20, 5), (8, 25))])
+    mesh = Mesh(np.array(jax.devices()[:1]), ("shard",))
+    monkeypatch.setattr(dist_step, "_STEP_CACHE", {})
+    # monkeypatch restores the process tracer (on or off) afterwards
+    monkeypatch.setattr(obs_trace, "_TRACER", None)
+
+    plain = cluster(pts, 2.0, 5, engine="distributed", mesh=mesh)
+    obs.enable()
+    traced = cluster(pts, 2.0, 5, engine="distributed", mesh=mesh)
+    names = {e["name"] for e in obs.get_tracer().snapshot_events()}
+
+    assert plain.n_clusters == 3
+    assert np.array_equal(plain.labels, traced.labels)
+    assert np.array_equal(plain.core, traced.core)
+    assert plain.overflow == traced.overflow
+    assert plain.attempts == traced.attempts
+    assert len(dist_step._STEP_CACHE) == 1
+    assert "dist.fit.spmd_step" in names
+    assert "dist.fit.halo_exchange" not in names

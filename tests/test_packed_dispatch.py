@@ -277,8 +277,8 @@ def test_boundary_census_bounds_halo_cap():
     worst = boundary_census(pts, 15.0, 4)
     cap = census_halo_cap(pts, 15.0, 4)
     assert cap >= worst
-    # quarter-pow2 ladder: over-provisioning bounded at 25% (the
-    # BENCH_8 halo padding-waste gate)
+    # quarter-pow2 ladder: over-provisioning bounded at 25% of the
+    # worst boundary side
     assert cap <= max(1.25 * worst, 32)
 
 

@@ -374,14 +374,29 @@ class TestServeIntegration:
         assert "serve.slab.imbalance" in names
         assert "serve.slab.load.0" in names
 
+    @staticmethod
+    def _assert_same_answers(a, b):
+        (srv_a, done_a), (srv_b, done_b) = a, b
+        assert len(done_a) == len(done_b)
+        for ra, rb in zip(done_a, done_b):
+            assert ra.kind == rb.kind
+            if ra.kind == "predict":
+                assert np.array_equal(ra.labels, rb.labels)
+        assert np.array_equal(srv_a.index.labels_arrival(),
+                              srv_b.index.labels_arrival())
+
     def test_rebalance_plane_applies_ops(self, blobs):
-        srv, _ = self._serve(
+        """Load-triggered splits under a serving stream answer every
+        request as a never-rebalanced topology does."""
+        plain = self._serve(blobs)
+        srv, done = self._serve(
             blobs, rebalance=RebalancePolicy(period=1, hot_factor=1.01,
                                              cold_factor=0.0))
         # aggressively low threshold -> at least one split happened
         assert srv.topology_events
         assert srv.index.num_shards > 3
         assert all(e["op"] == "split" for e in srv.topology_events)
+        self._assert_same_answers(plain, (srv, done))
 
     def test_rebalance_needs_topology_backend(self, blobs):
         from repro.serve.driver import ClusterServer
@@ -392,13 +407,8 @@ class TestServeIntegration:
     def test_replicated_reads_match_primary_serving(self, blobs):
         """Same request stream through a replicated server and a
         plain one: identical labels on every request."""
-        srv_a, done_a = self._serve(blobs)
+        plain = self._serve(blobs)
         srv_b, done_b = self._serve(blobs, replicas=2)
         assert len(srv_b.replicas) == 2
         assert srv_b._rr > 0            # reads actually fanned out
-        for ra, rb in zip(done_a, done_b):
-            assert ra.kind == rb.kind
-            if ra.kind == "predict":
-                assert np.array_equal(ra.labels, rb.labels)
-        assert np.array_equal(srv_a.index.labels_arrival(),
-                              srv_b.index.labels_arrival())
+        self._assert_same_answers(plain, (srv_b, done_b))
